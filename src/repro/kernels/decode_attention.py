@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams, interpret_default
+from .pallas_compat import interpret_default
 
 NEG_INF = -1e30
 
@@ -146,7 +146,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, block_k=128,
                 pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, D), jnp.float32),
             ]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, qg, kt, vt)
@@ -197,7 +197,7 @@ def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
                 pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, D), jnp.float32),
             ]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pt, pos_arr, qg, k_pages, v_pages)

@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dual_tenant_matmul import _schedule
-from .pallas_compat import CompilerParams, interpret_default
+from .pallas_compat import interpret_default
 
 NEG_INF = -1e30
 
@@ -149,7 +149,7 @@ def dual_tenant_attention(q_ls, k_ls, v_ls, q_be, k_be, v_be, *, sm_be=0.3,
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, D), jnp.float32),
             ]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(owner, row,

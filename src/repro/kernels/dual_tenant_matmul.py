@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
 
 
 def _schedule(n_ls: int, n_be: int, sm_be: float, round_tiles: int = 8):
@@ -135,7 +134,7 @@ def dual_tenant_matmul(a_ls, b_ls, a_be, b_be, *, sm_be=0.3, block_m=128,
             out_specs=(pl.BlockSpec((block_m, block_n), o_map(0)),
                        pl.BlockSpec((block_m, block_n), o_map(1))),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(owner, row, a_ls, b_ls, a_be, b_be)

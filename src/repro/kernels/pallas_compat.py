@@ -1,11 +1,5 @@
-"""Pallas API-skew shim: newer jax renamed ``pltpu.TPUCompilerParams`` to
-``pltpu.CompilerParams``. Import ``CompilerParams`` from here so the kernels
-build against both."""
+"""Execution-mode default shared by the Pallas kernel entry points."""
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
 
 
 def interpret_default() -> bool:

@@ -25,8 +25,9 @@ Abort/progress protocol (sub-chunk preemption): ``abort`` is a per-row cap
 on how many of the chunk's query positions may complete this launch.
 Compute for kv blocks past position ``pos + abort - 1`` is ``pl.when``-
 predicated off (abort == 0 skips the row entirely), rows at or past the cap
-are causally masked out, and a ``progress`` output reports per row how far
-the launch got — ``min(abort, Sq)``. Because each query row's online
+are causally masked out, and the wrapper reports per row how far the launch
+got — ``progress = min(abort, Sq)``, a function of the inputs alone, so the
+kernel emits no output for it. Because each query row's online
 softmax is independent and already causal, the first ``abort`` rows are
 bit-equal to running a chunk of exactly ``abort`` tokens, which is what
 lets the serving engine abort a BE chunk at tile granularity and later
@@ -42,13 +43,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams, interpret_default
+from .pallas_compat import interpret_default
 
 NEG_INF = -1e30
 
 
-def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref, prog_ref,
-            m_scr, l_scr, acc_scr, *, scale, block_k, sq, group):
+def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
+            m_scr, l_scr, acc_scr, *, scale, block_k, group):
     b = pl.program_id(0)
     ki = pl.program_id(2)
 
@@ -86,17 +87,14 @@ def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref, prog_ref,
     def _fin():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        prog_ref[0, 0] = jnp.minimum(abort_ref[b], sq)
 
 
 def _paged_kernel(pt_ref, pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
-                  prog_ref, m_scr, l_scr, acc_scr, *, scale, block_k, sq,
-                  group):
+                  m_scr, l_scr, acc_scr, *, scale, block_k, group):
     # the page table is consumed by the BlockSpec index maps only
     del pt_ref
-    _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref, prog_ref,
-            m_scr, l_scr, acc_scr, scale=scale, block_k=block_k, sq=sq,
-            group=group)
+    _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
+            m_scr, l_scr, acc_scr, scale=scale, block_k=block_k, group=group)
 
 
 def _abort_array(abort, B, Sq):
@@ -145,11 +143,9 @@ def prefill_attention(q, k_cache, v_cache, pos, *, block_k=128,
         last = pos[b] + jnp.maximum(ab[b], 1) - 1
         return (b, h, jnp.minimum(j, last // block_k), 0)
 
-    out, prog = pl.pallas_call(
-        functools.partial(_kernel, scale=D ** -0.5, block_k=block_k, sq=Sq,
-                          group=G),
-        out_shape=(jax.ShapeDtypeStruct((B, Hkv, Sq * G, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, 1), jnp.int32)),
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=D ** -0.5, block_k=block_k, group=G),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, Sq * G, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, Hkv, Smax // block_k),
@@ -159,16 +155,14 @@ def prefill_attention(q, k_cache, v_cache, pos, *, block_k=128,
                 pl.BlockSpec((1, 1, block_k, D), _kv_index),
                 pl.BlockSpec((1, 1, block_k, D), _kv_index),
             ],
-            out_specs=(pl.BlockSpec((1, 1, Sq * G, D),
-                                    lambda b, h, j, pos, ab: (b, h, 0, 0)),
-                       pl.BlockSpec((1, 1),
-                                    lambda b, h, j, pos, ab: (b, 0))),
+            out_specs=pl.BlockSpec((1, 1, Sq * G, D),
+                                   lambda b, h, j, pos, ab: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Sq * G, 1), jnp.float32),
                 pltpu.VMEM((Sq * G, 1), jnp.float32),
                 pltpu.VMEM((Sq * G, D), jnp.float32),
             ]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, abort_arr, qg, kt, vt)
@@ -176,7 +170,7 @@ def prefill_attention(q, k_cache, v_cache, pos, *, block_k=128,
              .reshape(B, Sq, H, D)
     if abort is None:
         return out
-    return out, prog[:, 0]
+    return out, abort_arr    # progress: the cap, already clamped to [0, Sq]
 
 
 def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
@@ -207,11 +201,10 @@ def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
         jj = jnp.minimum(j, last // page_size)
         return (jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
 
-    out, prog = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=D ** -0.5, block_k=page_size,
-                          sq=Sq, group=G),
-        out_shape=(jax.ShapeDtypeStruct((B, Hkv, Sq * G, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, 1), jnp.int32)),
+                          group=G),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, Sq * G, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, Hkv, P),
@@ -221,17 +214,14 @@ def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
                 pl.BlockSpec((1, 1, page_size, D), _kv_index),
                 pl.BlockSpec((1, 1, page_size, D), _kv_index),
             ],
-            out_specs=(pl.BlockSpec((1, 1, Sq * G, D),
-                                    lambda b, h, j, pt, pos, ab:
-                                    (b, h, 0, 0)),
-                       pl.BlockSpec((1, 1),
-                                    lambda b, h, j, pt, pos, ab: (b, 0))),
+            out_specs=pl.BlockSpec((1, 1, Sq * G, D),
+                                   lambda b, h, j, pt, pos, ab: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Sq * G, 1), jnp.float32),
                 pltpu.VMEM((Sq * G, 1), jnp.float32),
                 pltpu.VMEM((Sq * G, D), jnp.float32),
             ]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pt, pos_arr, abort_arr, qg, k_pages, v_pages)
@@ -239,4 +229,4 @@ def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
              .reshape(B, Sq, H, D)
     if abort is None:
         return out
-    return out, prog[:, 0]
+    return out, abort_arr    # progress: the cap, already clamped to [0, Sq]

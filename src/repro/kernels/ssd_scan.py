@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
 
 CLAMP = 20.0
 
@@ -68,7 +67,7 @@ def ssd_scan(q, k, v, log_w, *, chunk=64, interpret=False):
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
         scratch_shapes=[pltpu.VMEM((K, P), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tr(q), tr(k), tr(v), tr(log_w))

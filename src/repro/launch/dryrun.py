@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 DOC = """Multi-pod dry-run: lower + compile every (architecture x input-shape) cell
 on the production meshes (16x16 single-pod, 2x16x16 multi-pod), record
 memory/cost/collective metrics, and lower small unrolled probes to recover
@@ -15,6 +12,7 @@ Artifacts: artifacts/dryrun/<arch>__<shape>__<mesh>.json  (resumable)
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -207,6 +205,11 @@ def main():
     ap.add_argument("--set", action="append", default=[],
                     help="cfg override k=v (e.g. remat=dots)")
     args = ap.parse_args()
+    # the production meshes need 512 host devices; set before the backend
+    # starts, keeping any flags the caller already passed
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")).strip()
 
     from ..dist.sharding import set_attn_fallback
     set_attn_fallback(args.attn_fallback)

@@ -111,8 +111,6 @@ def parse_collectives(hlo_text: str, n_devices: int) -> CollectiveStats:
 
 def compiled_metrics(compiled, n_devices: int) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per program
-        ca = ca[0] if ca else {}
     mem = compiled.memory_analysis()
     txt = compiled.as_text()
     coll = parse_collectives(txt, n_devices)

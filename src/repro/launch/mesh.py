@@ -1,22 +1,14 @@
 """Production mesh construction. A FUNCTION, not a module-level constant, so
-importing this module never touches jax device state. ``make_mesh`` papers
-over the jax API skew: newer jax wants explicit ``axis_types``; older
-releases (<= 0.4.x) predate ``jax.sharding.AxisType`` entirely."""
+importing this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # older jax: no explicit-sharding axis types
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,32 +1,45 @@
 """Multi-tenant serving launcher (SGDRC on a local device).
 
-    python -m repro.launch.serve --ls qwen3-1.7b --be gemma2-9b \
-        --requests 8 --coloring --grid-search
+    python -m repro.launch.serve --ls qwen3-1.7b --be stablelm-1.6b \
+        --requests 8 --paged --use-flash --chunk-size 256 --grid-search
+    python -m repro.launch.serve --smoke --ls stablelm-1.6b --be stablelm-1.6b
 
-Runs reduced-config models for real through the continuous-batching
-ServingEngine (slot-pool batched prefill/decode; LS preempts BE at step
-boundaries, or lends BE the plan's sm_be quantum share when --grid-search
-derives a ResourcePlan; colored KV arenas when --coloring; page-table KV
-admission with --paged, optionally through the ragged Pallas flash-decode
-kernel with --use-flash; the full KV memory hierarchy with --grow-pages /
---swap / --cold-dtype). With --backend sim the same request stream drives
-the contention simulator instead (pod-scale what-if on the full configs;
-see also benchmarks/fig12_invram.py). --disagg swaps the single engine for
-the disaggregated prefill/decode pair over the modeled interconnect
+On the jax backend the tenants run for real through the continuous-batching
+ServingEngine at their published widths, with bfloat16 parameters and
+activations; ``--smoke`` serves the reduced float32 configs instead (CPU
+tests and quick checks). LS preempts BE at step boundaries, or lends BE the
+plan's sm_be quantum share when --grid-search derives a ResourcePlan from the
+configs being served; colored KV arenas when --coloring; page-table KV
+admission with --paged, optionally through the Pallas flash kernels with
+--use-flash; the full KV memory hierarchy with --grow-pages / --swap /
+--cold-dtype. With --backend sim the same request stream drives the
+contention simulator on the published configs instead (see also
+benchmarks/fig12_invram.py). --disagg swaps the single engine for the
+disaggregated prefill/decode pair over the modeled interconnect
 (serving.disagg; see benchmarks/disagg_bench.py).
+
+``build_engine`` and ``submit_requests`` are the one construction path and
+request loop; ``main`` and ``chip_smoke.py`` both call them.
 """
 import argparse
+import json
 
 import numpy as np
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ls", nargs="+", default=["qwen3-1.7b"])
-    ap.add_argument("--be", nargs="+", default=["gemma2-9b"])
+    ap.add_argument("--be", nargs="+", default=["stablelm-1.6b"])
+    ap.add_argument("--smoke", action="store_true",
+                    help="jax backend: serve the reduced float32 smoke "
+                         "configs instead of the published configs")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=4)
+    ap.add_argument("--max-seq", type=int, default=None,
+                    help="per-slot KV context in tokens (default: prompt "
+                         "length + max-new + 4)")
     ap.add_argument("--coloring", action="store_true")
     ap.add_argument("--backend", default="jax", choices=["jax", "sim"])
     ap.add_argument("--slots", type=int, default=4,
@@ -65,7 +78,8 @@ def main():
                          "bounded-error faults); fp16 = native-dtype "
                          "passthrough (bit-exact resume)")
     ap.add_argument("--use-flash", action="store_true",
-                    help="ragged Pallas flash-decode (interpret off-TPU)")
+                    help="Pallas flash kernels for decode and chunked "
+                         "prefill (interpret mode off-TPU)")
     ap.add_argument("--chunk-size", type=int, default=None,
                     help="chunked prefill: max prompt tokens one request "
                          "advances per engine quantum, so a long prompt "
@@ -143,31 +157,68 @@ def main():
                          "(plans/faults/violations), info = + request "
                          "phases/quanta/swaps/flows, debug = + per-chunk "
                          "and per-kernel events")
-    args = ap.parse_args()
+    return ap
 
+
+def served_config(name: str, args):
+    """The config tenant ``name`` is served with. The sim backend models the
+    published config; the jax backend runs it at its published widths in
+    bfloat16 (parameters are created in bfloat16, never cast down from a
+    float32 copy), or the reduced float32 variant under ``--smoke``."""
     from ..configs import get_config, smoke_config
+    if args.backend == "sim":
+        return get_config(name)
+    if args.smoke:
+        return smoke_config(name).replace(activation_dtype="float32")
+    return get_config(name).replace(param_dtype="bfloat16",
+                                    activation_dtype="bfloat16")
+
+
+def _plan(args):
+    """(plan, controller) from ``--online`` / ``--grid-search``, searched on
+    the configs being served."""
+    from ..core.controller import (OnlineController, frontier_search,
+                                   grid_search)
+    from ..core.simulator import GPU_DEVICES
+    if not (args.online or args.grid_search):
+        return None, None
+    dev = GPU_DEVICES[args.gpu]
+    ls_cfgs = [served_config(n, args) for n in args.ls]
+    be_cfgs = [served_config(n, args) for n in args.be]
+    if args.online:
+        frontier = frontier_search(dev, ls_cfgs, be_cfgs,
+                                   load_grid=(0.5, 1.0), pairs_per_model=1,
+                                   sm_grid=(0.2, 0.3, 0.4),
+                                   ch_grid=(1 / 4, 1 / 2),
+                                   thres_grid=(0.4,))
+        ctrl = OnlineController(frontier)
+        print("frontier: " + "; ".join(
+            f"load<={lvl:.2f}: SM_BE={p.sm_be:.2f} Ch_BE={p.ch_be:.2f}"
+            for lvl, p in frontier.entries))
+        return ctrl.plan, ctrl    # starting point = most conservative regime
+    plan = grid_search(dev, ls_cfgs, be_cfgs, pairs_per_model=2)
+    print(f"plan: SM_BE={plan.sm_be:.2f} Ch_BE={plan.ch_be:.2f} "
+          f"Thres_DRAM={plan.thres_dram:.2f} "
+          f"(worst LS inflation {plan.max_ls_inflation:.2f}x)")
+    return plan, None
+
+
+def _max_seq(args) -> int:
+    return args.max_seq or args.prompt_len + args.max_new + 4
+
+
+def build_engine(args, *, tracer=None, params=None):
+    """The ServingEngine and its tenants as ``args`` (from
+    :func:`build_parser`) describe them. ``params`` optionally maps tenant
+    names (``ls:<arch>`` / ``be:<arch>``) to parameters to serve instead of
+    the tenant's seeded initialisation."""
     from ..core.coloring import gpu_hash_model
-    from ..core.controller import (ChunkGovernor, OnlineController,
-                                   frontier_search, grid_search)
+    from ..core.controller import ChunkGovernor
     from ..core.simulator import GPU_DEVICES
     from ..core.tenancy import TenantSpec
     from ..serving import FaultPlane, ServingEngine
 
-    tracer = None
-    if args.trace:
-        from .. import obs
-        tracer = obs.Tracer(args.trace_level)
-
-    def _export_trace(events):
-        from ..obs import SLOTimeline, write_jsonl, write_perfetto
-        write_perfetto(events, args.trace)
-        write_jsonl(events, args.trace + ".jsonl")
-        print(f"trace: {len(events)} events -> {args.trace} "
-              f"(+.jsonl); flight-recorder dumps: {len(tracer.dumps)}")
-        tl = SLOTimeline(events)
-        if tl.dones:
-            print(tl.format_table())
-
+    params = params or {}
     faults = None
     now_fn = None
     if args.chaos:
@@ -184,66 +235,10 @@ def main():
                    "page_corrupt": 0.1, "alloc_fail": 0.05,
                    "ctl_missed_tick": 0.05, "bw_degrade": 0.05},
             duration=horizon / 10)
-
-    plan, ctrl = None, None
-    if args.online:
-        dev = GPU_DEVICES[args.gpu]
-        frontier = frontier_search(dev,
-                                   [smoke_config(n) for n in args.ls],
-                                   [smoke_config(n) for n in args.be],
-                                   load_grid=(0.5, 1.0), pairs_per_model=1,
-                                   sm_grid=(0.2, 0.3, 0.4),
-                                   ch_grid=(1 / 4, 1 / 2),
-                                   thres_grid=(0.4,))
-        ctrl = OnlineController(frontier)
-        plan = ctrl.plan       # starting point = most conservative regime
-        print("frontier: " + "; ".join(
-            f"load<={lvl:.2f}: SM_BE={p.sm_be:.2f} Ch_BE={p.ch_be:.2f}"
-            for lvl, p in frontier.entries))
-    elif args.grid_search:
-        dev = GPU_DEVICES[args.gpu]
-        plan = grid_search(dev,
-                           [smoke_config(n) for n in args.ls],
-                           [smoke_config(n) for n in args.be],
-                           pairs_per_model=2)
-        print(f"plan: SM_BE={plan.sm_be:.2f} Ch_BE={plan.ch_be:.2f} "
-              f"Thres_DRAM={plan.thres_dram:.2f} "
-              f"(worst LS inflation {plan.max_ls_inflation:.2f}x)")
-
-    if args.disagg:
-        if args.backend != "jax":
-            ap.error("--disagg runs on the jax backend")
-        import json
-        from ..serving import DisaggregatedEngine
-        dis = DisaggregatedEngine(
-            max_seq=args.prompt_len + args.max_new + 4,
-            page_size=args.page_size, chunk_size=args.chunk_size,
-            token_budget=args.token_budget, kv_pages=args.kv_pages,
-            slots_prefill=args.slots, slots_decode=args.slots,
-            n_devices=args.devices, n_prefill=args.prefill_devices,
-            pipeline=not args.no_pipeline,
-            control_interval=args.control_interval,
-            use_flash=args.use_flash, prefix_cache=args.prefix_cache,
-            tracer=tracer)
-        names = []
-        for name in args.ls:
-            cfg = smoke_config(name).replace(activation_dtype="float32")
-            dis.add_tenant(TenantSpec(f"ls:{name}", "LS", nice=10_000), cfg)
-            names.append(f"ls:{name}")
-        rng = np.random.default_rng(0)
-        for _ in range(args.requests):
-            for t in names:
-                dis.submit(t, rng.integers(0, 256, args.prompt_len).tolist(),
-                           max_new=args.max_new)
-        dis.run_until_idle()
-        print(json.dumps(dis.metrics(), indent=1))
-        if tracer is not None:
-            _export_trace(tracer.events)
-        return
-
+    plan, ctrl = _plan(args)
     grow = args.grow_pages or args.swap
     eng = ServingEngine(
-        max_seq=args.prompt_len + args.max_new + 4,
+        max_seq=_max_seq(args),
         backend=args.backend, plan=plan, coloring=args.coloring,
         paged=args.paged or args.prefix_cache or grow,
         page_size=args.page_size, kv_pages=args.kv_pages,
@@ -263,9 +258,7 @@ def main():
         now_fn=now_fn, tracer=tracer,
         hash_model=gpu_hash_model(args.gpu)
         if args.coloring and args.backend == "jax" else None)
-    rng = np.random.default_rng(0)
-    # jax backend executes reduced (smoke) models for real; the sim backend
-    # models the FULL configs at paper-scale request shapes. With
+    # the sim backend models paper-scale request shapes. With
     # --prefix-cache the sim tenants stay stream-derived (no sim_seq): the
     # prefix estimator only applies to request streams, so a fixed sim_seq
     # would silently disable the suffix-only prefill costing
@@ -273,36 +266,106 @@ def main():
     sim_seq_ls = None if args.prefix_cache else 128
     sim_seq_be = None if args.prefix_cache else 256
     for name in args.ls:
-        cfg = (get_config(name) if sim
-               else smoke_config(name).replace(activation_dtype="float32"))
-        eng.add_tenant(TenantSpec(f"ls:{name}", "LS", nice=10_000), cfg,
+        t = f"ls:{name}"
+        eng.add_tenant(TenantSpec(t, "LS", nice=10_000),
+                       served_config(name, args), params=params.get(t),
                        sim_seq=sim_seq_ls if sim else None)
     for name in args.be:
-        cfg = (get_config(name) if sim
-               else smoke_config(name).replace(activation_dtype="float32"))
-        eng.add_tenant(TenantSpec(f"be:{name}", "BE", nice=1, batch_size=8
-                                  if sim else 1), cfg,
+        t = f"be:{name}"
+        eng.add_tenant(TenantSpec(t, "BE", nice=1, batch_size=8
+                                  if sim else 1),
+                       served_config(name, args), params=params.get(t),
                        sim_seq=sim_seq_be if sim else None)
-    # with --prefix-cache, give the stream a shared system-prompt prefix so
-    # the radix tree has something to hit (drawn only then, so existing
-    # configurations keep their exact token streams)
+    return eng
+
+
+def submit_requests(eng, args):
+    """Queue ``args.requests`` seeded prompts of ``args.prompt_len`` tokens
+    per tenant (a shared system-prompt prefix with ``--prefix-cache``) and
+    return the submitted requests in order."""
+    rng = np.random.default_rng(0)
+    # the shared prefix is drawn only with --prefix-cache, so other
+    # configurations keep their exact token streams
     shared = (rng.integers(0, 256, args.prompt_len // 2)
               if args.prefix_cache else None)
+    reqs = []
     for i in range(args.requests):
         for t in eng.tenants:
             toks = rng.integers(0, 256, args.prompt_len)
             if args.prefix_cache:
                 toks[: len(shared)] = shared
-            eng.submit(t, toks, max_new=args.max_new,
-                       at=0.05 * i if args.backend == "sim" else None)
-    steps = eng.run_until_idle(horizon=args.requests * 0.1 + 2.0
-                               if args.backend == "sim" else None)
-    import json
-    print(json.dumps(eng.metrics(), indent=1))
-    print(f"engine quanta executed: {steps}" if args.backend == "jax"
-          else f"requests completed in sim: {steps}")
+            reqs.append(eng.submit(t, toks, max_new=args.max_new,
+                                   at=0.05 * i if args.backend == "sim"
+                                   else None))
+    return reqs
+
+
+def run(eng, args) -> int:
+    """Drive the engine until every submitted request is done; returns the
+    quanta executed (jax) or requests completed (sim)."""
+    return eng.run_until_idle(horizon=args.requests * 0.1 + 2.0
+                              if args.backend == "sim" else None)
+
+
+def _serve_disagg(args, tracer):
+    from ..core.tenancy import TenantSpec
+    from ..serving import DisaggregatedEngine
+    dis = DisaggregatedEngine(
+        max_seq=_max_seq(args),
+        page_size=args.page_size, chunk_size=args.chunk_size,
+        token_budget=args.token_budget, kv_pages=args.kv_pages,
+        slots_prefill=args.slots, slots_decode=args.slots,
+        n_devices=args.devices, n_prefill=args.prefill_devices,
+        pipeline=not args.no_pipeline,
+        control_interval=args.control_interval,
+        use_flash=args.use_flash, prefix_cache=args.prefix_cache,
+        tracer=tracer)
+    names = []
+    for name in args.ls:
+        dis.add_tenant(TenantSpec(f"ls:{name}", "LS", nice=10_000),
+                       served_config(name, args))
+        names.append(f"ls:{name}")
+    rng = np.random.default_rng(0)
+    for _ in range(args.requests):
+        for t in names:
+            dis.submit(t, rng.integers(0, 256, args.prompt_len).tolist(),
+                       max_new=args.max_new)
+    dis.run_until_idle()
+    print(json.dumps(dis.metrics(), indent=1))
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+    if args.disagg and args.backend != "jax":
+        ap.error("--disagg runs on the jax backend")
+
+    tracer = None
+    if args.trace:
+        from .. import obs
+        tracer = obs.Tracer(args.trace_level)
+
+    if args.disagg:
+        _serve_disagg(args, tracer)
+    else:
+        eng = build_engine(args, tracer=tracer)
+        submit_requests(eng, args)
+        steps = run(eng, args)
+        print(json.dumps(eng.metrics(), indent=1))
+        print(f"engine quanta executed: {steps}" if args.backend == "jax"
+              else f"requests completed in sim: {steps}")
     if tracer is not None:
-        _export_trace(tracer.events)
+        from ..obs import SLOTimeline, write_jsonl, write_perfetto
+        events = tracer.events
+        write_perfetto(events, args.trace)
+        write_jsonl(events, args.trace + ".jsonl")
+        print(f"trace: {len(events)} events -> {args.trace} "
+              f"(+.jsonl); flight-recorder dumps: {len(tracer.dumps)}")
+        tl = SLOTimeline(events)
+        if tl.dones:
+            print(tl.format_table())
 
 
 if __name__ == "__main__":

@@ -102,9 +102,10 @@ def make_steps(cfg: ModelConfig, opt_cfg: AdamWConfig = None):
     return train_step, prefill_step, serve_step
 
 
-def abstract_state(cfg: ModelConfig, mesh, key=jax.random.key(0),
-                   zero1: bool = True):
+def abstract_state(cfg: ModelConfig, mesh, key=None, zero1: bool = True):
     """Abstract params/opt/err + shardings (no allocation)."""
+    if key is None:
+        key = jax.random.key(0)
     params = jax.eval_shape(lambda k: tf.init_params(k, cfg), key)
     pspecs = param_pspecs(params, mesh)
     ospecs = zero1_pspecs(params, mesh, zero1)

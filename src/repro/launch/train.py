@@ -26,6 +26,8 @@ def main():
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.devices:
         os.environ["XLA_FLAGS"] = (

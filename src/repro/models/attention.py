@@ -22,11 +22,13 @@ NEG_INF = -2.0 ** 30  # large-negative that survives bf16
 
 def init_gqa(key, path, cfg, dtype):
     D, H, Hkv, Dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    # head-split weights: the fan-in is every input axis (D in, H*Dh out)
     p = {
-        "wq": dense_init(key, path + "/wq", (D, H, Dh), dtype),
-        "wk": dense_init(key, path + "/wk", (D, Hkv, Dh), dtype),
-        "wv": dense_init(key, path + "/wv", (D, Hkv, Dh), dtype),
-        "wo": dense_init(key, path + "/wo", (H, Dh, D), dtype),
+        "wq": dense_init(key, path + "/wq", (D, H, Dh), dtype, D ** -0.5),
+        "wk": dense_init(key, path + "/wk", (D, Hkv, Dh), dtype, D ** -0.5),
+        "wv": dense_init(key, path + "/wv", (D, Hkv, Dh), dtype, D ** -0.5),
+        "wo": dense_init(key, path + "/wo", (H, Dh, D), dtype,
+                         (H * Dh) ** -0.5),
     }
     if cfg.qk_norm:
         p["q_gamma"] = jnp.zeros((Dh,), dtype)
@@ -40,15 +42,19 @@ def init_mla(key, path, cfg, dtype):
     return {
         "wq_a": dense_init(key, path + "/wq_a", (D, m.q_lora_rank), dtype),
         "q_ln": jnp.zeros((m.q_lora_rank,), dtype),
-        "wq_b": dense_init(key, path + "/wq_b", (m.q_lora_rank, H, qk), dtype),
+        "wq_b": dense_init(key, path + "/wq_b", (m.q_lora_rank, H, qk), dtype,
+                           m.q_lora_rank ** -0.5),
         "wkv_a": dense_init(key, path + "/wkv_a",
                             (D, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_ln": jnp.zeros((m.kv_lora_rank,), dtype),
         "wk_b": dense_init(key, path + "/wk_b",
-                           (m.kv_lora_rank, H, m.qk_nope_head_dim), dtype),
+                           (m.kv_lora_rank, H, m.qk_nope_head_dim), dtype,
+                           m.kv_lora_rank ** -0.5),
         "wv_b": dense_init(key, path + "/wv_b",
-                           (m.kv_lora_rank, H, m.v_head_dim), dtype),
-        "wo": dense_init(key, path + "/wo", (H, m.v_head_dim, D), dtype),
+                           (m.kv_lora_rank, H, m.v_head_dim), dtype,
+                           m.kv_lora_rank ** -0.5),
+        "wo": dense_init(key, path + "/wo", (H, m.v_head_dim, D), dtype,
+                         (H * m.v_head_dim) ** -0.5),
     }
 
 
@@ -56,10 +62,13 @@ def init_cross_attn(key, path, cfg, kv_dim, dtype):
     D, H, Dh = cfg.d_model, cfg.num_heads, cfg.head_dim
     Hkv = cfg.num_kv_heads
     return {
-        "wq": dense_init(key, path + "/wq", (D, H, Dh), dtype),
-        "wk": dense_init(key, path + "/wk", (kv_dim, Hkv, Dh), dtype),
-        "wv": dense_init(key, path + "/wv", (kv_dim, Hkv, Dh), dtype),
-        "wo": dense_init(key, path + "/wo", (H, Dh, D), dtype),
+        "wq": dense_init(key, path + "/wq", (D, H, Dh), dtype, D ** -0.5),
+        "wk": dense_init(key, path + "/wk", (kv_dim, Hkv, Dh), dtype,
+                         kv_dim ** -0.5),
+        "wv": dense_init(key, path + "/wv", (kv_dim, Hkv, Dh), dtype,
+                         kv_dim ** -0.5),
+        "wo": dense_init(key, path + "/wo", (H, Dh, D), dtype,
+                         (H * Dh) ** -0.5),
         "gate": jnp.zeros((), dtype),   # VLM-style tanh gate on the residual
     }
 

@@ -2,9 +2,14 @@
 
 Parameters are plain nested dicts of jnp arrays (pytrees). Initializers take an
 explicit PRNG key; every leaf gets a key derived from its path so init is
-order-independent and reproducible.
+order-independent and reproducible across processes (the path is folded in
+through CRC-32, never through Python's per-process salted ``hash``). Leaves
+are drawn directly in their parameter dtype, so a bfloat16 model never
+materializes a float32 copy of its weights.
 """
 from __future__ import annotations
+
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -23,21 +28,31 @@ def dt(name: str):
 # init
 # ---------------------------------------------------------------------------
 
+def _crc(name: str) -> int:
+    return zlib.crc32(name.encode()) % (2**31)
+
+
 def _fold(key, path: str):
-    return jax.random.fold_in(key, np.uint32(abs(hash(path)) % (2**31)))
+    return jax.random.fold_in(key, np.uint32(_crc(path)))
+
+
+def name_key(name: str):
+    """PRNG key derived from a name (e.g. a serving tenant's), the same in
+    every process."""
+    return jax.random.key(_crc(name))
 
 
 def dense_init(key, path: str, shape, dtype, scale: float | None = None):
     """Truncated-normal fan-in init (scale defaults to 1/sqrt(fan_in))."""
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     s = scale if scale is not None else fan_in ** -0.5
-    return (jax.random.truncated_normal(_fold(key, path), -2.0, 2.0, shape,
-                                        jnp.float32) * s).astype(dtype)
+    return jax.random.truncated_normal(_fold(key, path), -2.0, 2.0, shape,
+                                       dtype) * jnp.asarray(s, dtype)
 
 
 def embed_init(key, path: str, shape, dtype):
-    return (jax.random.normal(_fold(key, path), shape, jnp.float32)
-            * shape[-1] ** -0.5).astype(dtype)
+    return jax.random.normal(_fold(key, path), shape, dtype) \
+        * jnp.asarray(shape[-1] ** -0.5, dtype)
 
 
 def zeros(shape, dtype):

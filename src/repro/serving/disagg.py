@@ -58,13 +58,13 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-import jax
 import numpy as np
 
 from ..core.compute import ElasticMeshPartitioner, LoadSignal
 from ..core.interconnect import (Flow, FlowCompletion, InterconnectSim,
                                  Topology)
 from ..models import transformer as tf
+from ..models.common import name_key
 from .engine import Request, ServingEngine
 from .scheduler import Phase
 
@@ -174,7 +174,7 @@ class DisaggregatedEngine:
         if params is None:
             params = tf.init_params(
                 key if key is not None
-                else jax.random.key(hash(spec.name) % 2**31), cfg)
+                else name_key(spec.name), cfg)
         prt = self.prefill.add_tenant(spec, cfg, params, n_slots=n_slots)
         drt = self.decode.add_tenant(spec, cfg, params, n_slots=n_slots)
         return prt, drt

@@ -101,6 +101,7 @@ from ..core.simulator import (GPU_DEVICES, GPUSimulator, Kernel, Tenant,
                               request_kernels)
 from ..core.tenancy import TenantSpec
 from ..models import transformer as tf
+from ..models.common import name_key
 from .. import obs
 from .faults import ColdPageCorrupt, FaultPlane, HostTierFault, safe_floor
 from .kv_cache import PagedKVCache, kv_bytes_per_token
@@ -752,6 +753,8 @@ class _JaxBackend:
             tokens += L * len(group)
             for j, req in enumerate(group):
                 req.prefill_pos = L
+                if eng.logits_hook is not None:
+                    eng.logits_hook(rt, req, last_logits[j, 0])
                 self._seed_first_token(rt, req, int(first[j]))
         return tokens
 
@@ -844,6 +847,8 @@ class _JaxBackend:
                         # pages while the remaining chunks still run
                         hook(rt, c.req)
                 for c in done:
+                    if eng.logits_hook is not None:
+                        eng.logits_hook(rt, c.req, logits[c.slot, 0])
                     self._seed_first_token(rt, c.req, int(arg[c.slot]))
             if eng.arrival_hook is not None:
                 eng.arrival_hook(wave_tokens)
@@ -922,6 +927,8 @@ class _JaxBackend:
         now = eng.clock()
         for s in slots:
             req = rt.active[s]
+            if eng.logits_hook is not None:
+                eng.logits_hook(rt, req, logits[s, 0])
             rt.pos[s] += 1
             tok = int(nxt[s])
             req.output.append(tok)
@@ -1287,6 +1294,10 @@ class ServingEngine:
                              else max(int(preempt_tile), 1))
         self.arrival_hook = arrival_hook
         self.preempt_hook = None
+        # logits_hook(rt, req, logits) (attribute) observes the [V] device
+        # logits row behind every token a request emits: the seeding
+        # prefill's row for its first token, then one per decode step
+        self.logits_hook = None
         self.preempt_aborts = 0
         self.preempt_waits: List[float] = []
         self._aborted_rids: set = set()
@@ -1399,7 +1410,7 @@ class ServingEngine:
         if params is None and self.backend_name == "jax":
             params = tf.init_params(
                 key if key is not None
-                else jax.random.key(hash(spec.name) % 2**31), cfg)
+                else name_key(spec.name), cfg)
         n_slots = n_slots or (self.slots_ls if spec.is_ls else self.slots_be)
         row_bytes = chans = None
         if self.arena is not None:

@@ -52,3 +52,22 @@ def test_small_mesh_dryrun_all_modes():
         assert v["flops"] > 0, k
         # the multi-pod mesh must actually communicate
     assert any(v["colls"] > 0 for k, v in out.items() if k.startswith("multi"))
+
+
+def test_launch_imports_touch_no_device_or_flags():
+    """Importing the dry-run and spec modules starts no backend and leaves
+    ``XLA_FLAGS`` as the caller set it (fresh interpreter: the test process
+    has long since started its backend)."""
+    script = (
+        "import os\n"
+        "os.environ['XLA_FLAGS'] = '--xla_dump_to=/dev/null'\n"
+        "import repro.launch.dryrun, repro.launch.specs, "
+        "repro.launch.compile_cache\n"
+        "from jax._src import xla_bridge\n"
+        "assert os.environ['XLA_FLAGS'] == '--xla_dump_to=/dev/null'\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]

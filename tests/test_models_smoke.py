@@ -2,6 +2,8 @@
 grad step on CPU; assert shapes and no NaNs. Plus decode-path consistency.
 Configs/params come from the cached ``smoke_model`` conftest factory so the
 three tests per arch share one init."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,3 +67,29 @@ def test_decode_matches_forward(name):
     dec = np.stack(outs, axis=1)
     np.testing.assert_allclose(dec, np.asarray(full_logits, np.float32),
                                rtol=2e-3, atol=2e-3)
+
+
+def test_init_keys_are_stable_across_processes():
+    """Leaf and tenant keys come from CRC-32 of their names, not from
+    Python's per-process salted ``hash``: the recorded values below hold in
+    every process, so a run on one machine can be checked against a
+    reference computed in another."""
+    from repro.models.common import _fold, name_key
+    leaf = _fold(jax.random.key(0), "layers/s0/attn/wq")
+    assert jax.random.key_data(leaf).tolist() == [224504919, 1878813183]
+    tenant = name_key("ls:qwen3-1.7b")
+    assert jax.random.key_data(tenant).tolist() == [0, 1051162302]
+
+
+def test_bf16_params_are_created_in_bf16():
+    """A bfloat16 config's leaves are drawn in bfloat16: no float32 array
+    (only the scalar truncation bounds) appears while they are made."""
+    cfg = smoke_config("qwen3-1.7b").replace(param_dtype="bfloat16")
+    params = jax.eval_shape(lambda k: tf.init_params(k, cfg),
+                            jax.random.key(0))
+    floats = [l.dtype for l in jax.tree.leaves(params)
+              if jnp.issubdtype(l.dtype, jnp.floating)]
+    assert floats and all(d == jnp.bfloat16 for d in floats)
+    jaxpr = str(jax.make_jaxpr(lambda k: tf.init_params(k, cfg))(
+        jax.random.key(0)))
+    assert not re.search(r"f32\[\d", jaxpr)
