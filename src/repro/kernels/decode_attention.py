@@ -71,8 +71,9 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                  acc_scr, *, scale, block_k):
+def _decode_attention_paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
+                                   o_ref, m_scr, l_scr, acc_scr, *, scale,
+                                   block_k):
     # the page table is consumed by the BlockSpec index maps only
     del pt_ref
     _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
@@ -179,7 +180,8 @@ def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
         return (jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=D ** -0.5, block_k=page_size),
+        functools.partial(_decode_attention_paged_kernel, scale=D ** -0.5,
+                          block_k=page_size),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

@@ -84,6 +84,7 @@ Scheduling invariants:
 """
 from __future__ import annotations
 
+import re
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -225,6 +226,20 @@ def _earliest_outstanding(rt: "_TenantRT") -> float:
     return min(ts) if ts else float("inf")
 
 
+def _tagged(fn, tenant: str):
+    """``fn`` renamed ``<name>__<tenant>`` (non-word characters as ``_``):
+    jit names its program after it."""
+    tag = re.sub(r"\W", "_", tenant)
+    fn.__name__ = fn.__qualname__ = f"{fn.__name__}__{tag}"
+    return fn
+
+
+def _rids(reqs) -> str:
+    """Request ids of a call's live rows, as one span argument: joined by
+    spaces, since the profiler ends an argument's value at a comma."""
+    return " ".join(str(r.rid) for r in reqs)
+
+
 def _scatter_rows(dst_cache, src_cache, slots):
     """Write the per-request rows of a freshly prefilled cache into the slot
     cache. ``layers`` leaves are [n_periods, B, ...] (batch axis 1, from the
@@ -277,17 +292,23 @@ class _JaxBackend:
                                    ctx_extra={"page_table": pt},
                                    use_flash=flash)
 
+        # each program is named after its tenant as well as its kind
+        # (``jit__decode_paged__ls_qwen3_1_7b``), so a profile says which
+        # tenant ran it
+        tenant = rt.spec.name
         # monolithic prompt processing survives only as the fallback for
         # models the cached-context chunk path can't serve (SSM state,
         # encoders, vision cross-attn: tf.chunkable is False)
-        rt.prefill_fn = jax.jit(_prefill, static_argnums=2)
+        rt.prefill_fn = jax.jit(_tagged(_prefill, tenant), static_argnums=2)
         if tf.chunkable(cfg):
-            rt.chunk_fn = jax.jit(_chunk_paged if eng.paged else _chunk,
-                                  donate_argnums=(2,))
+            rt.chunk_fn = jax.jit(
+                _tagged(_chunk_paged if eng.paged else _chunk, tenant),
+                donate_argnums=(2,))
         # the previous cache is dead after each decode step — donate it so
         # the one-token append is in-place instead of a full pool copy
-        rt.decode_fn = jax.jit(_decode_paged if eng.paged else _decode,
-                               donate_argnums=(2,))
+        rt.decode_fn = jax.jit(
+            _tagged(_decode_paged if eng.paged else _decode, tenant),
+            donate_argnums=(2,))
 
     def add_tenant(self, rt: _TenantRT):
         eng = self.engine
@@ -748,7 +769,8 @@ class _JaxBackend:
             last_logits, pcache = rt.prefill_fn(rt.params, toks, eng.max_seq)
             rt.cache = _scatter_rows(rt.cache, pcache,
                                      jnp.asarray(slots, jnp.int32))
-            first = np.asarray(jnp.argmax(last_logits[:, 0], axis=-1))
+            with obs.span("sync", tenant=rt.spec.name):
+                first = np.asarray(jnp.argmax(last_logits[:, 0], axis=-1))
             rt.prefill_computed += L * len(group)
             tokens += L * len(group)
             for j, req in enumerate(group):
@@ -779,6 +801,7 @@ class _JaxBackend:
         abort/progress protocol)."""
         eng = self.engine
         kv = rt.kv
+        tenant = rt.spec.name
         preemptable = bool(eng.preempt_tile) and not rt.spec.is_ls
         if preemptable:
             chunks = split_tiles(chunks, eng.preempt_tile)
@@ -794,26 +817,31 @@ class _JaxBackend:
             for c in wave:
                 by_len.setdefault(c.length, []).append(c)
             for Sq, group in by_len.items():
-                toks = np.zeros((rt.n_slots, Sq), np.int32)
-                pos = np.full(rt.n_slots, sentinel, np.int32)
-                for c in group:
-                    toks[c.slot] = c.req.tokens[c.start:c.start + Sq]
-                    pos[c.slot] = c.start
+                with obs.span("dispatch", tenant=tenant, kind="chunk", sq=Sq,
+                              slots=rt.n_slots, live=len(group),
+                              tokens=Sq * len(group),
+                              rids=_rids(c.req for c in group)):
+                    toks = np.zeros((rt.n_slots, Sq), np.int32)
+                    pos = np.full(rt.n_slots, sentinel, np.int32)
+                    for c in group:
+                        toks[c.slot] = c.req.tokens[c.start:c.start + Sq]
+                        pos[c.slot] = c.start
+                        if kv is not None:
+                            # fork every shared page this chunk writes into
+                            for pg in range(c.start // kv.page_size,
+                                            (c.start + Sq - 1)
+                                            // kv.page_size + 1):
+                                if kv.needs_fork(c.slot, pg * kv.page_size):
+                                    rt.cache = kv.fork_cow(rt.cache, c.slot,
+                                                           pg)
                     if kv is not None:
-                        # fork every shared page this chunk will write into
-                        for pg in range(c.start // kv.page_size,
-                                        (c.start + Sq - 1) // kv.page_size
-                                        + 1):
-                            if kv.needs_fork(c.slot, pg * kv.page_size):
-                                rt.cache = kv.fork_cow(rt.cache, c.slot, pg)
-                if kv is not None:
-                    logits, rt.cache = rt.chunk_fn(
-                        rt.params, jnp.asarray(toks), rt.cache,
-                        jnp.asarray(pos), kv.device_page_table())
-                else:
-                    logits, rt.cache = rt.chunk_fn(
-                        rt.params, jnp.asarray(toks), rt.cache,
-                        jnp.asarray(pos))
+                        logits, rt.cache = rt.chunk_fn(
+                            rt.params, jnp.asarray(toks), rt.cache,
+                            jnp.asarray(pos), kv.device_page_table())
+                    else:
+                        logits, rt.cache = rt.chunk_fn(
+                            rt.params, jnp.asarray(toks), rt.cache,
+                            jnp.asarray(pos))
                 rt.prefill_computed += Sq * len(group)
                 if eng.tracer.enabled("chunk"):
                     t_c = eng.clock()
@@ -838,7 +866,8 @@ class _JaxBackend:
                 done = [c for c in group
                         if c.start + Sq >= len(c.req.tokens)]
                 if done:
-                    arg = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                    with obs.span("sync", tenant=tenant):
+                        arg = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
                 hook = self.engine.chunk_hook
                 for c in group:
                     c.req.prefill_pos = c.start + Sq
@@ -846,10 +875,16 @@ class _JaxBackend:
                         # mid-prompt commit: stream the newly completed KV
                         # pages while the remaining chunks still run
                         hook(rt, c.req)
-                for c in done:
-                    if eng.logits_hook is not None:
-                        eng.logits_hook(rt, c.req, logits[c.slot, 0])
-                    self._seed_first_token(rt, c.req, int(arg[c.slot]))
+                if done:
+                    with obs.span("emit", tenant=tenant,
+                                  tokens=len(done)) as sp:
+                        for c in done:
+                            if eng.logits_hook is not None:
+                                eng.logits_hook(rt, c.req, logits[c.slot, 0])
+                            self._seed_first_token(rt, c.req,
+                                                   int(arg[c.slot]))
+                        sp.set_metadata(finished=sum(
+                            c.req.phase is Phase.FINISHED for c in done))
             if eng.arrival_hook is not None:
                 eng.arrival_hook(wave_tokens)
             if preemptable and any(by_slot.values()) \
@@ -901,50 +936,57 @@ class _JaxBackend:
         a slot prefilling across quanta is never corrupted by the decode
         batch it shares the pool with."""
         eng = self.engine
+        tenant = rt.spec.name
         rt.peak_active = max(rt.peak_active,
                              sum(r is not None for r in rt.active))
-        live = np.zeros(rt.n_slots, bool)
-        live[slots] = True
-        if rt.prefix is not None:
-            # safety net: a decode append must never mutate a shared page
-            # (admission reserves + chunk execution fork every predicted
-            # write, so this does not fire on the predicted paths)
+        with obs.span("dispatch", tenant=tenant, kind="decode", sq=1,
+                      slots=rt.n_slots, live=len(slots), tokens=len(slots),
+                      rids=_rids(rt.active[s] for s in slots)):
+            live = np.zeros(rt.n_slots, bool)
+            live[slots] = True
+            if rt.prefix is not None:
+                # safety net: a decode append must never mutate a shared
+                # page (admission reserves + chunk execution fork every
+                # predicted write, so this does not fire on the predicted
+                # paths)
+                for s in slots:
+                    if rt.kv.needs_fork(s, int(rt.pos[s])):
+                        rt.cache = rt.kv.fork_cow(
+                            rt.cache, s, int(rt.pos[s]) // rt.kv.page_size)
+            dec_pos = np.where(live, rt.pos,
+                               self._write_sentinel(rt)).astype(np.int32)
+            toks = jnp.asarray(rt.last_tok[:, None])
+            if rt.kv is not None:
+                logits, rt.cache = rt.decode_fn(rt.params, toks, rt.cache,
+                                                jnp.asarray(dec_pos),
+                                                rt.kv.device_page_table())
+            else:
+                logits, rt.cache = rt.decode_fn(rt.params, toks, rt.cache,
+                                                jnp.asarray(dec_pos))
+        with obs.span("sync", tenant=tenant):
+            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        with obs.span("emit", tenant=tenant, tokens=len(slots)) as sp:
+            now = eng.clock()
+            finished = 0
             for s in slots:
-                if rt.kv.needs_fork(s, int(rt.pos[s])):
-                    rt.cache = rt.kv.fork_cow(
-                        rt.cache, s, int(rt.pos[s]) // rt.kv.page_size)
-        dec_pos = np.where(live, rt.pos,
-                           self._write_sentinel(rt)).astype(np.int32)
-        toks = jnp.asarray(rt.last_tok[:, None])
-        if rt.kv is not None:
-            logits, rt.cache = rt.decode_fn(rt.params, toks, rt.cache,
-                                            jnp.asarray(dec_pos),
-                                            rt.kv.device_page_table())
-        else:
-            logits, rt.cache = rt.decode_fn(rt.params, toks, rt.cache,
-                                            jnp.asarray(dec_pos))
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-        now = eng.clock()
-        for s in slots:
-            req = rt.active[s]
-            if eng.logits_hook is not None:
-                eng.logits_hook(rt, req, logits[s, 0])
-            rt.pos[s] += 1
-            tok = int(nxt[s])
-            req.output.append(tok)
-            rt.last_tok[s] = tok
-            if req.t_last is not None:
-                rt.tbt_gaps.append(now - req.t_last)
-                if rt.spec.is_ls:
-                    eng.registry.histogram("ls_tbt_all_ms").record(
-                        (now - req.t_last) * 1e3)
-            req.t_last = now
-            if req.t_evicted is not None:   # first token after a swap-in
-                rt.resume_gaps.append(now - req.t_evicted)
-                req.t_evicted = None
-            if len(req.output) >= max(req.max_new, 1) \
-                    or rt.pos[s] >= eng.max_seq:
-                self._finish(rt, s)
+                req = rt.active[s]
+                if eng.logits_hook is not None:
+                    eng.logits_hook(rt, req, logits[s, 0])
+                rt.pos[s] += 1
+                tok = int(nxt[s])
+                req.output.append(tok)
+                rt.last_tok[s] = tok
+                if req.t_last is not None:
+                    rt.tbt_gaps.append(now - req.t_last)
+                req.t_last = now
+                if req.t_evicted is not None:   # first token after a swap-in
+                    rt.resume_gaps.append(now - req.t_evicted)
+                    req.t_evicted = None
+                if len(req.output) >= max(req.max_new, 1) \
+                        or rt.pos[s] >= eng.max_seq:
+                    self._finish(rt, s)
+                    finished += 1
+            sp.set_metadata(finished=finished)
 
     def quantum(self, rt: _TenantRT) -> bool:
         """One scheduler-composed quantum: decode first (every DECODING slot
@@ -956,35 +998,39 @@ class _JaxBackend:
         ticking."""
         eng = self.engine
         sched = eng.scheduler
+        tenant = rt.spec.name
         shed_now = 0
-        if eng.fault_recovery:
-            # deadline shed pre-pass: an expired queued (WAITING/SWAPPED)
-            # request is dropped before it can consume admission or pages —
-            # under a fault storm BE deadlines turn backlog into shed work
-            # instead of batch-wide stall
-            now = eng.clock()
-            for req in [r for r in rt.queue
-                        if r.deadline is not None and now > r.deadline]:
-                self._shed(rt, req, "deadline")
-                shed_now += 1
-        report = QuantumReport(rt.spec.name, rt.spec.priority,
-                               budget=sched.budget_for(rt.spec.priority))
-        dec = sched.decode_slots(rt)
-        if dec and eng.grow_pages and rt.kv is not None:
-            dec, report.swap_out_pages = self._ensure_growth(rt, dec)
+        with obs.span("sched", tenant=tenant):
+            if eng.fault_recovery:
+                # deadline shed pre-pass: an expired queued (WAITING/SWAPPED)
+                # request is dropped before it can consume admission or
+                # pages — under a fault storm BE deadlines turn backlog into
+                # shed work instead of batch-wide stall
+                now = eng.clock()
+                for req in [r for r in rt.queue
+                            if r.deadline is not None and now > r.deadline]:
+                    self._shed(rt, req, "deadline")
+                    shed_now += 1
+            report = QuantumReport(tenant, rt.spec.priority,
+                                   budget=sched.budget_for(rt.spec.priority))
+            dec = sched.decode_slots(rt)
+            if dec and eng.grow_pages and rt.kv is not None:
+                dec, report.swap_out_pages = self._ensure_growth(rt, dec)
         if dec:
             self._decode(rt, dec)
             report.decode_tokens = len(dec)
             if eng.arrival_hook is not None:
                 eng.arrival_hook(len(dec))
-        admitted = sched.admit(rt, eng)
-        if rt.host is not None:
-            report.swap_in_pages = self._swap_progress(rt)
-        if rt.chunk_fn is not None:
-            chunks = sched.prefill_chunks(rt, len(dec))
-            if chunks:
-                report.prefill_tokens = self._run_chunks(rt, chunks)
-        elif admitted:
+        chunks = None
+        with obs.span("sched", tenant=tenant):
+            admitted = sched.admit(rt, eng)
+            if rt.host is not None:
+                report.swap_in_pages = self._swap_progress(rt)
+            if rt.chunk_fn is not None:
+                chunks = sched.prefill_chunks(rt, len(dec))
+        if chunks:
+            report.prefill_tokens = self._run_chunks(rt, chunks)
+        elif admitted and rt.chunk_fn is None:
             report.prefill_tokens = self._prefill_monolithic(rt, admitted)
         progressed = bool(dec or admitted or report.prefill_tokens
                           or report.swap_in_pages or report.swap_out_pages
@@ -1625,6 +1671,13 @@ class ServingEngine:
                       for rt in self.tenants.values())
         if not due:
             return
+        with obs.span("control"):
+            self._control_tick()
+
+    def _control_tick(self):
+        """One control tick: read the window's load signal, then adopt the
+        controller's plan (or drain off-color pages left by an earlier
+        one)."""
         self._last_ctl_step = self._step_idx
         now = self.clock()
         if (self.faults is not None
@@ -1841,6 +1894,10 @@ class ServingEngine:
         quantum for one tenant of that class. LS strictly preempts BE at
         this boundary when no plan grants BE a share. With an online
         controller attached this boundary is also where re-plans land."""
+        with obs.span("step", step=self._step_idx):
+            return self._step()
+
+    def _step(self) -> bool:
         if (self.controller is not None or self.chunk_governor is not None) \
                 and self.backend_name == "jax":
             self._maybe_control()

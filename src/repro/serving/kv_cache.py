@@ -34,6 +34,7 @@ from ..core.coloring.allocator import ColoredArena, OutOfColoredMemory
 from ..core.costmodel import kv_token_bytes
 from ..models import transformer as tf
 from ..models.common import dt
+from ..obs.spans import span
 
 
 def kv_bytes_per_token(cfg: ModelConfig, dtype_bytes: Optional[int] = None
@@ -341,7 +342,8 @@ class PagedKVCache:
             dst = self.slot_reserve[slot].pop()
         else:                               # safety net: unpredicted fork
             dst = self._alloc_pages(slot, 1)[0]
-        pools = _copy_page_tree(pools, src, dst)
+        with span("pages", tenant=self.name):
+            pools = _copy_page_tree(pools, src, dst)
         self.page_ref[src] -= 1
         assert self.page_ref[src] >= 1, "shared page lost its tree owner"
         self.slot_shared[slot].remove(src)
@@ -446,7 +448,8 @@ class PagedKVCache:
         # cached between admit/evict boundaries: pure-decode stretches must
         # not pay a host->device transfer per step for an unchanged table
         if self._pt_dev is None:
-            self._pt_dev = jnp.asarray(self.page_table)
+            with span("pages", tenant=self.name):
+                self._pt_dev = jnp.asarray(self.page_table)
         return self._pt_dev
 
     def write_prefill(self, pools, prefill_cache, slots: Sequence[int],

@@ -124,3 +124,29 @@ def test_prefill_attention_paged_compiles(one_chip, widths, sq, page, abort):
         ((n_pages, Hkv, page, D), jnp.bfloat16),
         ((B, SMAX // page), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+def test_paged_kernels_carry_distinct_names(one_chip):
+    """Each paged kernel is named after its own wrapper, so a profile or an
+    HLO dump tells the decode kernel from the prefill kernel; both names
+    keep ``attention_paged``."""
+    H, Hkv, D = QWEN3
+    page = 128
+    n_pages = B * SMAX // page
+    pool = ((n_pages, Hkv, page, D), jnp.bfloat16)
+    table = ((B, SMAX // page), jnp.int32)
+    texts = {}
+    for name, kernel, q in (
+            ("decode", decode_attention_paged, (B, H, D)),
+            ("prefill", prefill_attention_paged, (B, 256, H, D))):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in ((q, jnp.bfloat16), pool, pool, table,
+                                     ((B,), jnp.int32))]
+        lowered = jax.jit(functools.partial(kernel, interpret=False)).lower(
+            *args)
+        texts[name] = lowered.as_text()
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    assert "_decode_attention_paged_kernel" in texts["decode"]
+    assert "_prefill_attention_paged_kernel" not in texts["decode"]
+    assert "_prefill_attention_paged_kernel" in texts["prefill"]
+    assert "_decode_attention_paged_kernel" not in texts["prefill"]
