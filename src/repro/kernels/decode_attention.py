@@ -6,9 +6,11 @@ Two entry points share one online-softmax kernel body:
                            [B,Hkv,Smax,D] via ``kv_layout="bhsd"``), with
                            *ragged* per-row valid lengths: ``pos`` may be a
                            scalar or a [B] vector (continuous batching).
-``decode_attention_paged`` page-pool cache [n_pages,Hkv,page,D] addressed
-                           through a per-row page table — the serving
-                           engine's PagedKVCache layout; no dense gather is
+``decode_attention_paged`` page-pool cache [n_pages,Hkv,page,D], or the
+                           whole layer stack of pools [L,n_pages,Hkv,page,D]
+                           read at one ``layer``, addressed through a per-row
+                           page table — the serving engine's PagedKVCache
+                           layout; no dense gather or per-layer slice is
                            materialized.
 
 Ragged early-exit: the kv grid axis is sequential and its BlockSpec index
@@ -33,8 +35,27 @@ from .pallas_compat import interpret_default
 NEG_INF = -1e30
 
 
+def scores(q, k_ref, d_major):
+    """q [rows, D] against a kv block held as it lies in HBM ([1, 1, bk, D],
+    or [1, 1, D, bk] for a D-major pool): [rows, bk] in float32."""
+    k = k_ref[0, 0].astype(jnp.float32)
+    if d_major:
+        return jnp.dot(q, k, preferred_element_type=jnp.float32)
+    return jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+
+
+def weighted_values(p, v_ref, d_major):
+    """p [rows, bk] times the value block (laid out as in :func:`scores`):
+    [rows, D] in float32."""
+    v = v_ref[0, 0].astype(jnp.float32)
+    if d_major:
+        return jax.lax.dot_general(p, v, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+    return jnp.dot(p, v, preferred_element_type=jnp.float32)
+
+
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, block_k):
+            scale, block_k, d_major=False):
     b = pl.program_id(0)
     ki = pl.program_id(2)
 
@@ -50,9 +71,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki <= pos_ref[b] // block_k)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # [G, D]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # [G, bk]
+        s = scores(q, k_ref, d_major)                        # [G, bk]
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos <= pos_ref[b], s, NEG_INF)
 
@@ -61,8 +80,8 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + weighted_values(p, v_ref,
+                                                              d_major)
         m_scr[...] = m_new
 
     @pl.when(ki == pl.num_programs(2) - 1)
@@ -71,13 +90,32 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _decode_attention_paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
-                                   o_ref, m_scr, l_scr, acc_scr, *, scale,
-                                   block_k):
-    # the page table is consumed by the BlockSpec index maps only
-    del pt_ref
+def _decode_attention_paged_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_ref,
+                                   v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                                   scale, block_k, d_major):
+    # the page table and the layer are consumed by the BlockSpec index maps
+    del pt_ref, layer_ref
     _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-            scale=scale, block_k=block_k)
+            scale=scale, block_k=block_k, d_major=d_major)
+
+
+def paged_pools(k_pages, v_pages, layer):
+    """The paged kernels' view of the pools: (k, v, layer [1] int32,
+    d_major). A 4-D pool [n_pages, Hkv, page, D] is the one-layer stack at
+    layer 0 (a free reshape). XLA's default TPU layout stores a [page, D]
+    tile D-major when D is under one 128-lane width and the page fills one
+    (stablelm: D 64, page 128); such pools are handed over transposed, [..,
+    D, page], which reads them as they lie (a bitcast) where a [page, D]
+    block would make XLA relayout every pool the kernel is given."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    page_size, D = k_pages.shape[-2:]
+    d_major = D < 128 <= page_size
+    if d_major:
+        k_pages = jnp.swapaxes(k_pages, -1, -2)
+        v_pages = jnp.swapaxes(v_pages, -1, -2)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    return k_pages, v_pages, layer, d_major
 
 
 def _pos_vector(pos, B):
@@ -155,45 +193,52 @@ def decode_attention(q, k_cache, v_cache, pos, *, block_k=128,
 
 
 def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
-                           interpret=None):
+                           layer=None, interpret=None):
     """Paged flash-decode: the KV lives in a shared page pool and each row's
     blocks are gathered through its page table *inside the BlockSpec index
     map* (one page = one kv block; no [B,Smax] dense view is materialized).
 
-    q: [B,H,D]; {k,v}_pages: [n_pages,Hkv,page_size,D]; page_table: [B,P]
-    int32 (entries >= n_pages are unmapped — they are never touched because
-    the kv index map clamps to the row's last valid page); pos: [B] int32.
-    The visible window is P * page_size tokens. Returns [B,H,D].
+    q: [B,H,D]; {k,v}_pages: [n_pages,Hkv,page_size,D], or the stacked
+    pools of every layer [L,n_pages,Hkv,page_size,D] with ``layer`` the one
+    to read (the index map addresses it, so no per-layer slice is copied; a
+    4-D pool is the ``L = 1``, ``layer = 0`` case); page_table: [B,P] int32
+    (entries >= n_pages are unmapped — they are never touched because the kv
+    index map clamps to the row's last valid page); pos: [B] int32. The
+    visible window is P * page_size tokens. Returns [B,H,D].
     """
     if interpret is None:
         interpret = interpret_default()
     B, H, D = q.shape
-    n_pages, Hkv, page_size, _ = k_pages.shape
+    k_pages, v_pages, layer_arr, d_major = paged_pools(k_pages, v_pages,
+                                                       layer)
+    _, n_pages, Hkv = k_pages.shape[:3]
+    tile = k_pages.shape[3:]              # (page, D), or (D, page) D-major
+    page_size = tile[1] if d_major else tile[0]
     P = page_table.shape[1]
     G = H // Hkv
     qg = q.reshape(B, Hkv, G, D)
     pos_arr = _pos_vector(pos, B)
     pt = jnp.asarray(page_table, jnp.int32)
 
-    def _kv_index(b, h, j, pt, pos):
+    def _kv_index(b, h, j, pt, pos, layer):
         jj = jnp.minimum(j, pos[b] // page_size)
-        return (jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
+        return (layer[0], jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_decode_attention_paged_kernel, scale=D ** -0.5,
-                          block_k=page_size),
+                          block_k=page_size, d_major=d_major),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, Hkv, P),
             in_specs=[
                 pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, j, pt, pos: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, D), _kv_index),
-                pl.BlockSpec((1, 1, page_size, D), _kv_index),
+                             lambda b, h, j, pt, pos, layer: (b, h, 0, 0)),
+                pl.BlockSpec((None, 1, 1) + tile, _kv_index),
+                pl.BlockSpec((None, 1, 1) + tile, _kv_index),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, j, pt, pos: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec(
+                (1, 1, G, D), lambda b, h, j, pt, pos, layer: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, 1), jnp.float32),
@@ -202,5 +247,5 @@ def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pt, pos_arr, qg, k_pages, v_pages)
+    )(pt, pos_arr, layer_arr, qg, k_pages, v_pages)
     return out.reshape(B, H, D)

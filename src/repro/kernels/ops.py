@@ -40,9 +40,9 @@ def decode_attention(q, k_cache, v_cache, pos, *, block_k=128,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
-                           interpret=None):
+                           layer=None, interpret=None):
     interpret = _interpret_default() if interpret is None else interpret
-    return _decode_paged(q, k_pages, v_pages, page_table, pos,
+    return _decode_paged(q, k_pages, v_pages, page_table, pos, layer=layer,
                          interpret=interpret)
 
 
@@ -56,9 +56,9 @@ def prefill_attention(q, k_cache, v_cache, pos, *, block_k=128,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
-                            interpret=None, abort=None):
+                            layer=None, interpret=None, abort=None):
     interpret = _interpret_default() if interpret is None else interpret
-    return _prefill_paged(q, k_pages, v_pages, page_table, pos,
+    return _prefill_paged(q, k_pages, v_pages, page_table, pos, layer=layer,
                           interpret=interpret, abort=abort)
 
 

@@ -8,10 +8,12 @@ generalisation of ``decode_attention``):
 ``prefill_attention``        dense KV-major cache [B,Hkv,Smax,D] with
                              per-row chunk start positions ``pos`` [B]:
                              the query at pos+i sees keys <= pos+i.
-``prefill_attention_paged``  page-pool cache [n_pages,Hkv,page,D] addressed
-                             through a per-row page table (the serving
-                             engine's PagedKVCache layout; no dense gather
-                             is materialized).
+``prefill_attention_paged``  page-pool cache [n_pages,Hkv,page,D], or the
+                             layer stack [L,n_pages,Hkv,page,D] read at one
+                             ``layer``, addressed through a per-row page
+                             table (the serving engine's PagedKVCache
+                             layout; no dense gather or per-layer slice is
+                             materialized).
 
 The chunk's own K/V must already be resident in the cache (the jnp-side
 scatter in ``models.attention`` runs before the call). All query heads AND
@@ -43,13 +45,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .decode_attention import paged_pools, scores, weighted_values
 from .pallas_compat import interpret_default
 
 NEG_INF = -1e30
 
 
 def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale, block_k, group):
+            m_scr, l_scr, acc_scr, *, scale, block_k, group, d_major=False):
     b = pl.program_id(0)
     ki = pl.program_id(2)
 
@@ -65,9 +68,7 @@ def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
              & (ki <= (pos_ref[b] + abort_ref[b] - 1) // block_k))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # [Sq*G, D]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [Sq*G, bk]
+        s = scores(q, k_ref, d_major)                        # [Sq*G, bk]
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
         q_pos = pos_ref[b] + row
@@ -79,8 +80,8 @@ def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + weighted_values(p, v_ref,
+                                                              d_major)
         m_scr[...] = m_new
 
     @pl.when(ki == pl.num_programs(2) - 1)
@@ -89,13 +90,15 @@ def _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _prefill_attention_paged_kernel(pt_ref, pos_ref, abort_ref, q_ref, k_ref,
-                                    v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                                    scale, block_k, group):
-    # the page table is consumed by the BlockSpec index maps only
-    del pt_ref
+def _prefill_attention_paged_kernel(pt_ref, pos_ref, abort_ref, layer_ref,
+                                    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                                    acc_scr, *, scale, block_k, group,
+                                    d_major):
+    # the page table and the layer are consumed by the BlockSpec index maps
+    del pt_ref, layer_ref
     _kernel(pos_ref, abort_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, scale=scale, block_k=block_k, group=group)
+            m_scr, l_scr, acc_scr, scale=scale, block_k=block_k, group=group,
+            d_major=d_major)
 
 
 def _abort_array(abort, B, Sq):
@@ -175,12 +178,14 @@ def prefill_attention(q, k_cache, v_cache, pos, *, block_k=128,
 
 
 def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
-                            interpret=None, abort=None):
+                            layer=None, interpret=None, abort=None):
     """Paged chunked-prefill flash attention: each row's kv blocks are
     gathered through its page table inside the BlockSpec index map (one page
     = one kv block, no dense window view).
 
-    q: [B,Sq,H,D]; {k,v}_pages: [n_pages,Hkv,page_size,D]; page_table:
+    q: [B,Sq,H,D]; {k,v}_pages: [n_pages,Hkv,page_size,D], or the stacked
+    pools of every layer [L,n_pages,Hkv,page_size,D] with ``layer`` the one
+    to read (a 4-D pool is the ``L = 1``, ``layer = 0`` case); page_table:
     [B,P] int32 (entries >= n_pages unmapped — never touched, the index map
     clamps to the row's last valid page); pos: [B] int32 chunk starts.
     Returns [B,Sq,H,D]; with ``abort`` returns ``(out, progress)`` under the
@@ -188,7 +193,11 @@ def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
     if interpret is None:
         interpret = interpret_default()
     B, Sq, H, D = q.shape
-    n_pages, Hkv, page_size, _ = k_pages.shape
+    k_pages, v_pages, layer_arr, d_major = paged_pools(k_pages, v_pages,
+                                                       layer)
+    _, n_pages, Hkv = k_pages.shape[:3]
+    tile = k_pages.shape[3:]              # (page, D), or (D, page) D-major
+    page_size = tile[1] if d_major else tile[0]
     P = page_table.shape[1]
     G = H // Hkv
     qg = q.reshape(B, Sq, Hkv, G, D).transpose(0, 2, 1, 3, 4) \
@@ -197,26 +206,27 @@ def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
     pt = jnp.asarray(page_table, jnp.int32)
     abort_arr = _abort_array(abort, B, Sq)
 
-    def _kv_index(b, h, j, pt, pos, ab):
+    def _kv_index(b, h, j, pt, pos, ab, layer):
         last = pos[b] + jnp.maximum(ab[b], 1) - 1
         jj = jnp.minimum(j, last // page_size)
-        return (jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
+        return (layer[0], jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_prefill_attention_paged_kernel, scale=D ** -0.5,
-                          block_k=page_size, group=G),
+                          block_k=page_size, group=G, d_major=d_major),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Sq * G, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, Hkv, P),
             in_specs=[
                 pl.BlockSpec((1, 1, Sq * G, D),
-                             lambda b, h, j, pt, pos, ab: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, D), _kv_index),
-                pl.BlockSpec((1, 1, page_size, D), _kv_index),
+                             lambda b, h, j, pt, pos, ab, layer: (b, h, 0, 0)),
+                pl.BlockSpec((None, 1, 1) + tile, _kv_index),
+                pl.BlockSpec((None, 1, 1) + tile, _kv_index),
             ],
-            out_specs=pl.BlockSpec((1, 1, Sq * G, D),
-                                   lambda b, h, j, pt, pos, ab: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec(
+                (1, 1, Sq * G, D),
+                lambda b, h, j, pt, pos, ab, layer: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Sq * G, 1), jnp.float32),
                 pltpu.VMEM((Sq * G, 1), jnp.float32),
@@ -225,7 +235,7 @@ def prefill_attention_paged(q, k_pages, v_pages, page_table, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pt, pos_arr, abort_arr, qg, k_pages, v_pages)
+    )(pt, pos_arr, abort_arr, layer_arr, qg, k_pages, v_pages)
     out = out.reshape(B, Hkv, Sq, G, D).transpose(0, 2, 1, 3, 4) \
              .reshape(B, Sq, H, D)
     if abort is None:

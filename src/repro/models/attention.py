@@ -307,32 +307,100 @@ def gqa_prefill_step(p, x, cfg, cache_k, cache_v, pos, *, layer_kind="global",
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache_k, cache_v
 
 
-def _page_lookup(page_table, positions, ps, n_pages):
-    """(physical page, in-page offset) per (row, token) for an append at
-    ``positions`` [B,Q]; unmapped entries — negative table slots or logical
-    pages past the table — land on the ``n_pages`` sentinel so a
-    ``mode="drop"`` scatter writes nothing (an out-of-table position must
-    never clamp onto a live — possibly shared — page)."""
+def _paged_append(pools, layer, news, page_table, pos, *, seq_axis):
+    """Write an Sq-token chunk per row into ``layer`` of stacked page pools,
+    where the pools lie, and return them.
+
+    pools: a tuple of [L, n_pages, *page] (K and V, or the two latent
+    pools), the page's token axis at ``seq_axis`` of ``page``; news: the
+    matching [B, Sq, *entry] (``page`` without its token axis); pos: [B]
+    chunk starts; page_table: [B, P] (negative entries, entries >= n_pages
+    and logical pages past the table are unmapped).
+
+    A row's Sq tokens touch at most ``n_seg`` pages. Those pages are read,
+    the row's tokens merged in, and the pages written back whole, by one
+    gather and one scatter per pool: only the touched pages move, with no
+    per-layer slice of the pool, and a whole-page window leaves XLA no
+    reason to relayout the pool (a token-granular scatter does). Rows at
+    unmapped positions (the write sentinel) and pages that take none of a
+    row's tokens are not written. A page written here belongs to one row
+    (pages are shared only read-only, and forked before a write), so no two
+    written pages collide."""
+    B, Sq = news[0].shape[:2]
+    n_pages, ps = pools[0].shape[1], pools[0].shape[2 + seq_axis]
     P = page_table.shape[1]
-    logical = positions // ps
-    off = positions % ps
+    n_seg = (Sq + ps - 2) // ps + 1
+    logical = pos[:, None] // ps + jnp.arange(n_seg)[None, :]   # [B, n_seg]
     phys = jnp.take_along_axis(page_table, jnp.clip(logical, 0, P - 1),
                                axis=1)
-    return jnp.where((phys < 0) | (logical >= P), n_pages, phys), off
+    mapped = (phys >= 0) & (phys < n_pages) & (logical < P)
+    tok = ((logical * ps)[..., None] + jnp.arange(ps)
+           - pos[:, None, None])                                # [B,n_seg,ps]
+    keep = mapped[..., None] & (tok >= 0) & (tok < Sq)
+    rows = (jnp.arange(B)[:, None], jnp.clip(tok, 0, Sq - 1).reshape(B, -1))
+    dest = jnp.where(keep.any(axis=-1), phys, n_pages)   # n_pages: dropped
+    phys = jnp.clip(phys, 0, n_pages - 1)
+    out = []
+    for pool, new in zip(pools, news):
+        src = new[rows].reshape((B, n_seg, ps) + new.shape[2:])
+        src = jnp.moveaxis(src.astype(pool.dtype), 2, 2 + seq_axis)
+        n_entry = new.ndim - 2
+        pages = jnp.where(
+            keep.reshape((B, n_seg) + (1,) * seq_axis + (ps,)
+                         + (1,) * (n_entry - seq_axis)),
+            src, pool[layer, phys])                      # [B, n_seg, *page]
+        out.append(pool.at[layer, dest].set(pages, mode="drop"))
+    return out
+
+
+def _gqa_paged(p, x, cfg, k_pages, v_pages, page_table, pos, layer,
+               layer_kind, use_flash, chunk):
+    """Shared body of the paged GQA decode and chunk steps: append the
+    chunk's K/V at ``layer`` of the stacked pools, then attend through the
+    page table (the chunked-prefill kernel if ``chunk``, else the decode
+    kernel)."""
+    B, Sq, _ = x.shape
+    n_pages = k_pages.shape[1]
+    Dh = k_pages.shape[-1]
+    positions = pos[:, None] + jnp.arange(Sq)[None, :]        # [B, Sq]
+    q, k, v = _proj_qkv(p, x, cfg, positions)       # k,v: [B,Sq,Hkv,Dh]
+    k_pages, v_pages = _paged_append((k_pages, v_pages), layer, (k, v),
+                                     page_table, pos, seq_axis=1)
+    if use_flash and not cfg.attn_logit_softcap and \
+            not (layer_kind == "local" and cfg.local_window):
+        from ..kernels import ops as kops
+        if chunk:
+            out = kops.prefill_attention_paged(q, k_pages, v_pages,
+                                               page_table, pos, layer=layer)
+        else:
+            out = kops.decode_attention_paged(q[:, 0], k_pages, v_pages,
+                                              page_table, pos,
+                                              layer=layer)[:, None]
+        out = out.astype(x.dtype)
+    else:
+        pt = jnp.clip(page_table, 0, n_pages - 1)
+        kd = k_pages[layer, pt]                     # [B,P,Hkv,ps,Dh]
+        vd = v_pages[layer, pt]
+        P, Hkv, ps = pt.shape[1], kd.shape[2], kd.shape[3]
+        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
+        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
+        out = _decode_core(q, kd, vd, positions, cfg, layer_kind, x.dtype)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k_pages, v_pages
 
 
 def gqa_decode_paged(p, x, cfg, k_pages, v_pages, page_table, pos, *,
-                     layer_kind="global", use_flash=False):
+                     layer, layer_kind="global", use_flash=False):
     """One-token decode against a paged KV cache (serving fast path).
 
-    k_pages/v_pages: [n_pages, Hkv, page_size, Dh] — a page pool shared by
-    every slot of the tenant (carved from the ColoredArena by
-    ``serving.kv_cache.PagedKVCache``); page_table: [B, P] int32 mapping
-    each row's logical pages to pool pages (entries >= n_pages are
-    unmapped); pos: scalar or [B].
+    k_pages/v_pages: [L, n_pages, Hkv, page_size, Dh] — every layer's page
+    pool, shared by every slot of the tenant (carved from the ColoredArena
+    by ``serving.kv_cache.PagedKVCache``), of which this call reads and
+    writes ``layer``; page_table: [B, P] int32 mapping each row's logical
+    pages to pool pages (entries >= n_pages are unmapped); pos: scalar or
+    [B].
 
-    The append touches exactly one page per row (an O(tokens) scatter — no
-    full-cache rewrite), and unmapped rows drop their writes. The read
+    The append rewrites one page per row, the one that takes its token (no
+    copy of the layer's pool), and unmapped rows drop their writes. The read
     side: ``use_flash`` gathers pages inside the kernel's BlockSpec index
     map (no dense copy, per-row early exit — the real-hardware path); the
     jnp fallback materializes a dense [B, P*page_size] window view first,
@@ -340,68 +408,27 @@ def gqa_decode_paged(p, x, cfg, k_pages, v_pages, page_table, pos, *,
     not a traffic win. Returns (out [B,1,D], new_k_pages, new_v_pages).
     """
     B = x.shape[0]
-    n_pages, Hkv, ps, Dh = k_pages.shape
-    P = page_table.shape[1]
-    positions = jnp.broadcast_to(jnp.asarray(pos), (B,))[:, None]
-    q, k, v = _proj_qkv(p, x, cfg, positions)       # k,v: [B,1,Hkv,Dh]
-    phys, off = _page_lookup(page_table, positions, ps, n_pages)
-    k_pages = k_pages.at[phys, :, off, :].set(
-        k.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[phys, :, off, :].set(
-        v.astype(v_pages.dtype), mode="drop")
-    if use_flash and not cfg.attn_logit_softcap and \
-            not (layer_kind == "local" and cfg.local_window):
-        from ..kernels import ops as kops
-        out = kops.decode_attention_paged(
-            q[:, 0], k_pages, v_pages, page_table,
-            positions[:, 0].astype(jnp.int32))
-        out = out[:, None].astype(x.dtype)
-    else:
-        pt = jnp.clip(page_table, 0, n_pages - 1)
-        kd = jnp.take(k_pages, pt, axis=0)          # [B,P,Hkv,ps,Dh]
-        vd = jnp.take(v_pages, pt, axis=0)
-        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
-        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
-        out = _decode_core(q, kd, vd, positions, cfg, layer_kind, x.dtype)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k_pages, v_pages
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    return _gqa_paged(p, x, cfg, k_pages, v_pages, page_table, pos, layer,
+                      layer_kind, use_flash, chunk=False)
 
 
 def gqa_prefill_paged(p, x, cfg, k_pages, v_pages, page_table, pos, *,
-                      layer_kind="global", use_flash=False):
+                      layer, layer_kind="global", use_flash=False):
     """Cached-context chunked prefill against a paged KV cache: the paged
     counterpart of :func:`gqa_prefill_step` (and the batched replacement for
     the prefix cache's one-token-per-step suffix replay).
 
-    x: [B,Sq,D]; pools/page_table as in :func:`gqa_decode_paged`; pos: [B]
-    chunk start positions. The Sq appends scatter one (page, offset) entry
-    per token (rows with unmapped or out-of-table positions drop); the read
-    side gathers the per-row window — through the chunked-prefill Pallas
-    kernel's BlockSpec index map under ``use_flash``, or a dense window view
-    in the jnp correctness path. Returns (out [B,Sq,D], new pools)."""
-    B, Sq, _ = x.shape
-    n_pages, Hkv, ps, Dh = k_pages.shape
-    P = page_table.shape[1]
-    positions = pos[:, None] + jnp.arange(Sq)[None, :]        # [B, Sq]
-    q, k, v = _proj_qkv(p, x, cfg, positions)       # k,v: [B,Sq,Hkv,Dh]
-    phys, off = _page_lookup(page_table, positions, ps, n_pages)
-    k_pages = k_pages.at[phys, :, off, :].set(
-        k.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[phys, :, off, :].set(
-        v.astype(v_pages.dtype), mode="drop")
-    if use_flash and not cfg.attn_logit_softcap and \
-            not (layer_kind == "local" and cfg.local_window):
-        from ..kernels import ops as kops
-        out = kops.prefill_attention_paged(
-            q, k_pages, v_pages, page_table, pos.astype(jnp.int32))
-        out = out.astype(x.dtype)
-    else:
-        pt = jnp.clip(page_table, 0, n_pages - 1)
-        kd = jnp.take(k_pages, pt, axis=0)          # [B,P,Hkv,ps,Dh]
-        vd = jnp.take(v_pages, pt, axis=0)
-        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
-        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
-        out = _decode_core(q, kd, vd, positions, cfg, layer_kind, x.dtype)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k_pages, v_pages
+    x: [B,Sq,D]; pools/page_table/layer as in :func:`gqa_decode_paged`;
+    pos: [B] chunk start positions. The Sq appends write each row's tokens
+    page by page in place (rows with unmapped or out-of-table positions
+    drop); the read side gathers the per-row window — through the
+    chunked-prefill Pallas kernel's BlockSpec index map under
+    ``use_flash``, or a dense window view in the jnp correctness path.
+    Returns (out [B,Sq,D], new pools)."""
+    return _gqa_paged(p, x, cfg, k_pages, v_pages, page_table,
+                      pos.astype(jnp.int32), layer, layer_kind, use_flash,
+                      chunk=True)
 
 
 # ---------------------------------------------------------------------------
@@ -534,56 +561,49 @@ def mla_prefill_step(p, x, cfg, cache_ckv, cache_krope, pos, **_):
             cache_ckv, cache_krope)
 
 
-def mla_decode_paged(p, x, cfg, ckv_pages, krope_pages, page_table, pos, **_):
-    """Paged MLA decode: the latent cache lives in a shared page pool.
-
-    ckv_pages: [n_pages, page_size, R]; krope_pages: [n_pages, page_size,
-    rope]; page_table: [B, P] int32 (entries >= n_pages unmapped). The
-    append writes one (page, offset) latent row per batch row; attention
-    runs over the per-row gathered window of P * page_size tokens.
-    """
-    B = x.shape[0]
-    n_pages, ps, R = ckv_pages.shape
-    P = page_table.shape[1]
-    positions = jnp.broadcast_to(jnp.asarray(pos), (B,))[:, None]
-    q_nope, q_rope = _mla_q(p, x, cfg, positions)
-    c_kv, k_rope = _mla_latent(p, x, cfg, positions)
-    phys, off = _page_lookup(page_table, positions, ps, n_pages)
-    ckv_pages = ckv_pages.at[phys, off, :].set(
-        c_kv.astype(ckv_pages.dtype), mode="drop")
-    krope_pages = krope_pages.at[phys, off, :].set(
-        k_rope.astype(krope_pages.dtype), mode="drop")
-    pt = jnp.clip(page_table, 0, n_pages - 1)
-    ckv = jnp.take(ckv_pages, pt, axis=0).reshape(B, P * ps, R)
-    krope = jnp.take(krope_pages, pt, axis=0).reshape(
-        B, P * ps, krope_pages.shape[-1])
-    return (_mla_core(p, x, cfg, q_nope, q_rope, ckv, krope, positions),
-            ckv_pages, krope_pages)
-
-
-def mla_prefill_paged(p, x, cfg, ckv_pages, krope_pages, page_table, pos,
-                      **_):
-    """Cached-context chunked MLA prefill against the paged latent pool:
-    Sq (page, offset) latent appends per row (unmapped positions drop),
-    attention over the per-row gathered window. Returns (out [B,Sq,D],
-    new pools)."""
+def _mla_paged(p, x, cfg, ckv_pages, krope_pages, page_table, pos, layer):
+    """Append the chunk's latents at ``layer`` of the stacked latent pools
+    and attend over each row's gathered window."""
     B, Sq, _ = x.shape
-    n_pages, ps, R = ckv_pages.shape
-    P = page_table.shape[1]
+    n_pages = ckv_pages.shape[1]
     positions = pos[:, None] + jnp.arange(Sq)[None, :]        # [B, Sq]
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
     c_kv, k_rope = _mla_latent(p, x, cfg, positions)
-    phys, off = _page_lookup(page_table, positions, ps, n_pages)
-    ckv_pages = ckv_pages.at[phys, off, :].set(
-        c_kv.astype(ckv_pages.dtype), mode="drop")
-    krope_pages = krope_pages.at[phys, off, :].set(
-        k_rope.astype(krope_pages.dtype), mode="drop")
+    ckv_pages, krope_pages = _paged_append(
+        (ckv_pages, krope_pages), layer, (c_kv, k_rope), page_table, pos,
+        seq_axis=0)
     pt = jnp.clip(page_table, 0, n_pages - 1)
-    ckv = jnp.take(ckv_pages, pt, axis=0).reshape(B, P * ps, R)
-    krope = jnp.take(krope_pages, pt, axis=0).reshape(
-        B, P * ps, krope_pages.shape[-1])
+    ckv = ckv_pages[layer, pt].reshape(B, -1, ckv_pages.shape[-1])
+    krope = krope_pages[layer, pt].reshape(B, -1, krope_pages.shape[-1])
     return (_mla_core(p, x, cfg, q_nope, q_rope, ckv, krope, positions),
             ckv_pages, krope_pages)
+
+
+def mla_decode_paged(p, x, cfg, ckv_pages, krope_pages, page_table, pos, *,
+                     layer, **_):
+    """Paged MLA decode: the latent cache lives in a shared page pool.
+
+    ckv_pages: [L, n_pages, page_size, R]; krope_pages: [L, n_pages,
+    page_size, rope] (every layer's pools, of which ``layer`` is read and
+    written); page_table: [B, P]
+    int32 (entries >= n_pages unmapped). The append rewrites, in place, the
+    page that takes each row's new latent; attention runs over the per-row
+    gathered window of P * page_size tokens.
+    """
+    B = x.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    return _mla_paged(p, x, cfg, ckv_pages, krope_pages, page_table, pos,
+                      layer)
+
+
+def mla_prefill_paged(p, x, cfg, ckv_pages, krope_pages, page_table, pos, *,
+                      layer, **_):
+    """Cached-context chunked MLA prefill against the paged latent pools:
+    each row's Sq latents are appended in place (unmapped positions drop),
+    then attention runs over the per-row gathered window. Returns (out
+    [B,Sq,D], new pools)."""
+    return _mla_paged(p, x, cfg, ckv_pages, krope_pages, page_table,
+                      pos.astype(jnp.int32), layer)
 
 
 # ---------------------------------------------------------------------------

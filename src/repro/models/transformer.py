@@ -179,8 +179,10 @@ def _mlp_or_moe(p, h, cfg, aux):
     return mlpm.mlp_forward(p["mlp"], h, cfg.mlp_act), aux
 
 
-def _attn_layer(p, x, cfg, kind, ctx, aux, cache=None, pos=None):
-    """Pre-norm attention + MLP/MoE block. Returns (x, aux, new_cache)."""
+def _attn_layer(p, x, cfg, kind, ctx, aux, cache=None, pos=None, layer=None):
+    """Pre-norm attention + MLP/MoE block. Returns (x, aux, new_cache).
+    With a paged cache, ``layer`` is this layer's index into the stacked
+    pools ``cache`` holds (None: ``cache`` holds this layer's pools alone)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     new_cache = cache
     if kind == "cross":
@@ -199,17 +201,23 @@ def _attn_layer(p, x, cfg, kind, ctx, aux, cache=None, pos=None):
         # contract (Sq prompt tokens per row at per-row start positions)
         pt = ctx["page_table"]
         chunk = ctx.get("chunk", False)
+        one = layer is None     # this layer's pools alone: a 1-layer stack
+        if one:
+            cache, layer = jax.tree.map(lambda a: a[None], cache), 0
         if cfg.attn_type == "mla":
             fn = attn.mla_prefill_paged if chunk else attn.mla_decode_paged
             a, ckv, kr = fn(p["attn"], h, cfg, cache["ckv"], cache["krope"],
-                            pt, pos)
+                            pt, pos, layer=layer)
             new_cache = {"ckv": ckv, "krope": kr}
         else:
             fn = attn.gqa_prefill_paged if chunk else attn.gqa_decode_paged
             a, ck, cv = fn(
                 p["attn"], h, cfg, cache["k"], cache["v"], pt, pos,
-                layer_kind=kind, use_flash=ctx.get("use_flash", False))
+                layer=layer, layer_kind=kind,
+                use_flash=ctx.get("use_flash", False))
             new_cache = {"k": ck, "v": cv}
+        if one:
+            new_cache = jax.tree.map(lambda a: a[0], new_cache)
     else:
         chunk = ctx.get("chunk", False)
         if cfg.attn_type == "mla":
@@ -275,12 +283,14 @@ def _shared_block(sp, x, x0, cfg, inv_idx, aux, ctx, cache=None, pos=None):
     return x + (out - h), aux, new_cache
 
 
-def _apply_one(p, x, cfg, kind, ctx, aux, cache, pos, period_idx, slot):
+def _apply_one(p, x, cfg, kind, ctx, aux, cache, pos, period_idx, slot,
+               layer=None):
     """Apply one pattern slot (possibly + shared block)."""
     p = cast_tree(p, cfg)
     base = _kind_base(kind)
     if base in ("global", "local", "cross"):
-        x, aux, nc = _attn_layer(p, x, cfg, base, ctx, aux, cache, pos)
+        x, aux, nc = _attn_layer(p, x, cfg, base, ctx, aux, cache, pos,
+                                 layer)
         if cfg.encoder and base == "global" and "cross_p" in ctx:
             cp = jax.tree.map(lambda a: a[period_idx], ctx["cross_p"])
             x = _whisper_cross(cp, x, cfg, ctx)
@@ -297,11 +307,19 @@ def _apply_one(p, x, cfg, kind, ctx, aux, cache, pos, period_idx, slot):
 # stack
 # ---------------------------------------------------------------------------
 
-def _period_body(cfg, period, ctx, with_cache):
+def _period_body(cfg, period, ctx, with_cache, paged=False):
+    """One period of the layer loop. A dense cache comes in per period as
+    a loop input and leaves as a loop output; a paged cache (``paged``)
+    rides in the carry as the stacked pools of every period, which each
+    attention layer updates in place at its period index — handing the loop
+    one period's pools would copy them out of the stack and back in, in
+    every layer of every step."""
     n_shared_per = max(1, sum(1 for k in period if k.endswith(SHARED_SUFFIX)))
 
     def body(carry, inp):
-        if with_cache:
+        if paged:
+            (x, aux, pos, cache_period), (p_period, idx) = carry, inp
+        elif with_cache:
             (x, aux, pos), (p_period, cache_period, idx) = carry, inp
         else:
             (x, aux), (p_period, idx) = carry, inp
@@ -311,7 +329,8 @@ def _period_body(cfg, period, ctx, with_cache):
         for j, kind in enumerate(period):
             p = p_period[f"s{j}"]
             c = cache_period[f"s{j}"] if with_cache else None
-            x, aux, nc = _apply_one(p, x, cfg, kind, ctx, aux, c, pos, idx, j)
+            x, aux, nc = _apply_one(p, x, cfg, kind, ctx, aux, c, pos, idx, j,
+                                    layer=idx if paged else None)
             new_caches[f"s{j}"] = nc
             if kind.endswith(SHARED_SUFFIX):
                 inv = idx * n_shared_per + shared_i
@@ -321,6 +340,8 @@ def _period_body(cfg, period, ctx, with_cache):
                 if with_cache:
                     new_caches["shared"] = nsc
                 shared_i += 1
+        if paged:
+            return (x, aux, pos, new_caches), None
         if with_cache:
             return (x, aux, pos), new_caches
         return (x, aux), None
@@ -356,14 +377,25 @@ def _apply_stack(params, cfg, x, ctx, cache=None, pos=None):
     if cfg.family == "hybrid":
         ctx["shared_p"] = params["shared"]
     with_cache = cache is not None
-    body = _period_body(cfg, period, ctx, with_cache)
+    # paged pools (pageable models: no shared block) are loop-carried
+    paged = with_cache and "page_table" in ctx
+    body = _period_body(cfg, period, ctx, with_cache, paged)
     if cfg.remat != "none":
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                   if cfg.remat == "dots"
                   else jax.checkpoint_policies.nothing_saveable)
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
     idxs = jnp.arange(n_periods)
-    if cfg.scan_layers and not with_cache:
+    if paged:
+        carry = (x, aux, jnp.asarray(pos, jnp.int32), cache["layers"])
+        if cfg.scan_layers:
+            carry, _ = jax.lax.scan(body, carry, (params["layers"], idxs))
+        else:
+            for i in range(n_periods):
+                p_i = jax.tree.map(lambda a: a[i], params["layers"])
+                carry, _ = body(carry, (p_i, i))
+        x, aux, _, new_cache["layers"] = carry
+    elif cfg.scan_layers and not with_cache:
         (x, aux), _ = jax.lax.scan(body, (x, aux), (params["layers"], idxs))
     elif cfg.scan_layers:
         (x, aux, _), stack = jax.lax.scan(

@@ -10,6 +10,7 @@ describes it loads the TPU library and holds it until it exits, so no call
 may happen while test modules are imported or collected.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -150,3 +151,77 @@ def test_paged_kernels_carry_distinct_names(one_chip):
     assert "_prefill_attention_paged_kernel" not in texts["decode"]
     assert "_prefill_attention_paged_kernel" in texts["prefill"]
     assert "_decode_attention_paged_kernel" not in texts["prefill"]
+
+
+# (opcode of an instruction, or the name of a fusion) that moves a whole pool
+_POOL_COPIES = ("copy", "dynamic-update-slice", "dynamic-slice")
+_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "pred": 1, "u8": 1, "s8": 1}
+
+
+def _pool_copies(hlo_text, min_bytes):
+    """Instructions outside the Pallas calls that copy at least
+    ``min_bytes``: ``copy`` instructions, and fusions named after a copy or
+    a dynamic (update-)slice, whose array result is that large."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        n = _BYTES.get(dtype, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        if n < min_bytes:
+            continue
+        if opcode == "copy" or (opcode == "fusion" and any(
+                c in name for c in _POOL_COPIES)):
+            out.append(f"{name} {dtype}[{dims}]")
+    return out
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk256"])
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "stablelm-1.6b"])
+def test_paged_step_updates_pools_in_place(one_chip, monkeypatch, name, kind):
+    """The whole served step at published widths (bf16, 8 slots x 2048
+    tokens, 128-token pages, the cache donated as the engine donates it)
+    updates the KV pools where they lie: no instruction outside the Pallas
+    kernels copies a layer's pool or more, and the step's temporary is below
+    one layer's K+V pools. Handing the layer loop one layer's pools at a
+    time copied them out of the stack and back in every layer (temporaries
+    of 1.95e9 bytes for the qwen3 decode step, 4.33e9 for the stablelm
+    chunk step)."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import transformer as tf
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = get_config(name).replace(param_dtype="bfloat16",
+                                   activation_dtype="bfloat16")
+    slots, page = 8, 128
+    n_pages = slots * SMAX // page
+    sq = 1 if kind == "decode" else 256
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(
+        lambda: tf.init_params(jax.random.key(0), cfg)))
+    cache = abstract(jax.eval_shape(
+        lambda: tf.init_paged_cache(cfg, n_pages, page, jnp.bfloat16)))
+    tokens, pos, table = abstract((
+        jax.ShapeDtypeStruct((slots, sq), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots, SMAX // page), jnp.int32)))
+    step = tf.decode_step if kind == "decode" else tf.prefill_step
+
+    def fn(p, t, c, q, pt):
+        return step(p, cfg, t, c, q, ctx_extra={"page_table": pt},
+                    use_flash=True)
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, tokens, cache, pos, table).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    k_pool = cache["layers"]["s0"]["k"]
+    layer_k = k_pool.size // k_pool.shape[0] * k_pool.dtype.itemsize
+    assert _pool_copies(txt, layer_k) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_k

@@ -453,6 +453,43 @@ def test_prefill_attention_paged_abort_progress():
                                atol=2e-6)
 
 
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "prefill-abort"])
+@pytest.mark.parametrize("ps,D", [(16, 64), (128, 64)],
+                         ids=["p16", "p128-dmajor"])
+def test_paged_kernels_read_one_layer_of_a_stack(kernel, ps, D):
+    """A stacked pool [L, n_pages, Hkv, ps, D] read at ``layer`` gives what
+    the 4-D call on ``pool[layer]`` gives, for every layer of the stack; a
+    128-token page of D 64 takes the D-major view (the TPU's own layout for
+    that tile) and still matches the oracle."""
+    L, B, H, Hkv, Sq = 3, 2, 4, 2, 5
+    P, n_pages = 2, 5
+    ks = jax.random.split(jax.random.key(31), 3)
+    kp = _rand(ks[0], (L, n_pages, Hkv, ps, D), jnp.float32)
+    vp = _rand(ks[1], (L, n_pages, Hkv, ps, D), jnp.float32)
+    pt = jnp.asarray([[3, 1], [0, n_pages]], jnp.int32)
+    pos = jnp.asarray([ps + 2, ps - 3], jnp.int32)
+    if kernel == "decode":
+        q = _rand(ks[2], (B, H, D), jnp.float32)
+        call = ops.decode_attention_paged
+        oracle = ref.ref_decode_attention_paged
+        kw = {}
+    else:
+        q = _rand(ks[2], (B, Sq, H, D), jnp.float32)
+        call = ops.prefill_attention_paged
+        oracle = ref.ref_prefill_attention_paged
+        kw = ({"abort": jnp.asarray([2, 0], jnp.int32)}
+              if kernel == "prefill-abort" else {})
+    for layer in range(L):
+        got = call(q, kp, vp, pt, pos, layer=jnp.asarray(layer), **kw)
+        one = call(q, kp[layer], vp[layer], pt, pos, **kw)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(one)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if not kw:
+            want = oracle(q, kp[layer], vp[layer], pt, pos)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-5, atol=2e-5)
+
+
 def test_interpret_autodetect():
     """``interpret=None`` resolves from the backend (CPU hosts interpret)
     and matches an explicit ``interpret=True`` bit-for-bit."""
