@@ -20,13 +20,12 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import reference, stats, traffic, weights, workcount
+from . import reference, spans, stats, traffic, weights, workcount
 from . import tracing as tr
-from .model import Model
 from .spec import REPO_DIR
 
 # JAX's persistent compilation cache, inside the checkout at a fixed path
@@ -74,9 +73,10 @@ class RunRecord:
     """What one run saw, for the metric readers (``bench/metrics``)."""
     workload: str
     seconds: float
-    models: Dict[str, Model]
+    models: Dict[str, Any]             # tenant -> its architecture's model
     tenants: Dict[str, str]            # class -> tenant name
     limits: Dict[str, dict]            # class -> {"ttft_ms", "tbt_ms"}
+    archs: Dict[str, Any] = field(default_factory=dict)  # tenant -> module
     sent: List[traffic.Sent] = field(default_factory=list)
     calls: List[Call] = field(default_factory=list)      # in the window
     steps: List[StepRec] = field(default_factory=list)   # in the window
@@ -133,9 +133,11 @@ def end_to_end(rec: RunRecord, setup_s: float) -> dict:
 
 # -- engine construction ------------------------------------------------
 
-def build(conf: dict, seed: int, models: Dict[str, Model]):
+def build(conf: dict, seed: int, models: dict, archs: dict):
     """The engine, built by the program's own launcher from the argv the
-    configuration file states, serving the benchmark's seeded weights."""
+    configuration file states, serving the benchmark's seeded weights
+    (``models`` and ``archs``: each tenant's model and its architecture
+    module)."""
     from repro.launch import serve
     argv = ["--ls", conf["ls"]["model"]]
     if "be" in conf:
@@ -143,11 +145,12 @@ def build(conf: dict, seed: int, models: Dict[str, Model]):
     args = serve.build_parser().parse_args(argv + conf["serve_argv"])
     if "be" not in conf:
         args.be = []      # the launcher's flag takes at least one name
-    params = {t: weights.make(m, seed, t) for t, m in models.items()}
+    params = {t: weights.make(archs[t], m, seed, t)
+              for t, m in models.items()}
     with contextlib.redirect_stdout(sys.stderr):
         eng = serve.build_engine(args, params=params)
     for t, m in models.items():
-        bad = m.mismatches(eng.tenants[t].cfg)
+        bad = archs[t].mismatches(m, eng.tenants[t].cfg)
         if bad:
             raise ValueError(f"{t}: the program does not serve the "
                              f"configuration file's model: {bad}")
@@ -361,15 +364,24 @@ def window_calls(rec: RunRecord, calls: list):
 def reduce_trace(rec: RunRecord, path, calls: list) -> dict:
     """Device numbers of the traced slice of the window: busy time and the
     slice's length, the breakdown, and each call made while the profiler
-    ran paired with its step program's device time and its attention
-    kernels' device time. Calls and step programs are paired in the order
-    the device ran them; a count that differs leaves the pairs out (and
-    the metrics that read them silent)."""
+    ran paired with its step program's device time, its kernels' device
+    time (the markers of the served architectures' ``kernels``) and its
+    device time by operation name. Calls and step programs are paired in
+    the order the device ran them; a count that differs leaves the pairs
+    out (and the metrics that read them silent). Also the slice's bounds
+    on the profiler's clock (``slice``), the engine's spans of the profile
+    (``engine_spans``, with their arguments; readers clip them to the
+    slice) and, where the device ran, the slice's intervals with no device
+    operation (``idle``)."""
     trace = tr.load(path)
     span = tr.window(trace)
-    if span is None or not trace.ops:
+    if span is None:
         return {}
     lo, hi = span.start, span.end
+    kept = {"slice": (lo, hi), "engine_spans": trace.program}
+    if not trace.ops:
+        return kept
+    markers = sorted({k for a in rec.archs.values() for k in a.kernels})
     mods = tr.step_modules(trace, float("-inf"), float("inf"))
     aligned = len(mods) == len(calls)
     labels = [f"{c.tenant}/{c.kind}{c.sq if c.kind == 'chunk' else ''}"
@@ -381,9 +393,11 @@ def reduce_trace(rec: RunRecord, path, calls: list) -> dict:
            "breakdown": {
                "device_ops": tr.top_ops(trace, mods, labels, lo, hi),
                "idle_gaps": [[k, v / 1e9] for k, v in sorted(
-                   gaps.items(), key=lambda kv: -kv[1])[:10]]}}
+                   gaps.items(), key=lambda kv: -kv[1])[:10]]},
+           "idle": spans.idle_intervals(trace.ops, lo, hi), **kept}
     if aligned:
-        out["aligned"] = list(zip(calls, tr.per_module(trace, mods)))
+        out["aligned"] = list(zip(calls, tr.per_module(trace, mods,
+                                                       markers)))
     return out
 
 
@@ -409,23 +423,23 @@ def pick_checked(sent: List[traffic.Sent], cls: str, seed: int) -> list:
     return out
 
 
-def check(samples: list, m: Model, seed: int, tenant: str, max_seq: int,
+def check(samples: list, arch, m, seed: int, tenant: str, max_seq: int,
           control: bool) -> dict:
     """Widest gap, over the sampled requests' served tokens, by which a
     served token's reference logit lies below the reference's best; with
     ``control`` also the same for the fp8 control's own first choice at
     each of those positions."""
-    params = weights.make(m, seed, tenant)
+    params = weights.make(arch, m, seed, tenant)
     worst, worst_c, n = 0.0, None, 0
     for prompt, out in samples:
         seq = np.concatenate([prompt, np.asarray(out[:-1], np.int32)])
         rows = np.arange(len(prompt) - 1, len(prompt) - 1 + len(out))
-        ref = reference.logits(params, m, seq, rows, pad_to=max_seq)
+        ref = reference.logits(params, arch, m, seq, rows, pad_to=max_seq)
         worst = max(worst, float(reference.gaps(ref, out).max()))
         n += len(out)
         if control:
-            ctl = reference.logits(params, m, seq, rows, pad_to=max_seq,
-                                   precision="fp8")
+            ctl = reference.logits(params, arch, m, seq, rows,
+                                   pad_to=max_seq, precision="fp8")
             g = float(reference.gaps(ref, ctl.argmax(-1)).max())
             worst_c = g if worst_c is None else max(worst_c, g)
     del params
@@ -474,18 +488,20 @@ COMPILES = "/jax/core/compile/backend_compile_duration"
 CACHE_HITS = "/jax/compilation_cache/cache_hits"
 
 
-def _tenants(conf: dict):
-    """({tenant: Model}, {class: tenant}, {class: LS limits}) of a
-    configuration file."""
-    models, tenants, limits = {}, {}, {}
+def _tenants(spec, conf: dict):
+    """({tenant: model}, {tenant: architecture module}, {class: tenant},
+    {class: LS limits}) of a configuration file; each tenant group names
+    its architecture (``bench/arch/<arch>.py``)."""
+    models, archs, tenants, limits = {}, {}, {}, {}
     for cls in ("ls", "be"):
         if cls in conf:
             t = f"{cls}:{conf[cls]['model']}"
-            models[t] = Model.from_conf(conf[cls], conf["dtype"])
+            archs[t] = spec.arch(conf[cls]["arch"])
+            models[t] = archs[t].model(conf[cls], conf["dtype"])
             tenants[cls.upper()] = t
             if "limits" in conf[cls]:
                 limits[cls.upper()] = conf[cls]["limits"]
-    return models, tenants, limits
+    return models, archs, tenants, limits
 
 
 def _measure(eng, rec, streams, calls, trace, keep_trace, log):
@@ -533,7 +549,8 @@ def _verify(conf, rec, samples, seed, max_seq, control) -> tuple:
     checks, correct = {}, True
     for cls, t in rec.tenants.items():
         lim = conf[cls.lower()]["logit_gap_limit"]
-        res = check(samples[cls], rec.models[t], seed, t, max_seq, control)
+        res = check(samples[cls], rec.archs[t], rec.models[t], seed, t,
+                    max_seq, control)
         checks[f"{cls.lower()}_logit_gap"] = {
             "value": res["gap"], "limit": lim, "tokens": res["tokens"]}
         if control:
@@ -561,18 +578,18 @@ def run(spec, workload: str, seed: int, seconds: float, trace: bool, *,
     cache_dir = use_checkout_cache() if compile_cache else None
     log(f"info: device {device['kind']} x{device['count']} "
         f"({device['platform']}); compile cache {cache_dir}")
-    models, tenants, limits = _tenants(conf)
+    models, archs, tenants, limits = _tenants(spec, conf)
     streams = traffic.streams(spec, spec.traffic(w["traffic"]), seed,
                               seconds, TAIL_S)
     if set(streams) - set(tenants):
         raise ValueError(f"mix {w['traffic']} sends traffic that "
                          f"configuration {w['config']} does not serve")
-    rec = RunRecord(workload, seconds, models, tenants, limits,
+    rec = RunRecord(workload, seconds, models, tenants, limits, archs,
                     t_start=t_start)
     if device["platform"] == "tpu":
         rec.peak = workcount.peaks(device["kind"])
 
-    eng = build(conf, seed, models)
+    eng = build(conf, seed, models, archs)
     max_seq = eng.max_seq
     calls: list = []
     instrument(eng, calls, fault)

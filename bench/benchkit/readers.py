@@ -1,6 +1,7 @@
 """Shared by the per-layer readers of step and kernel metrics: the traced
-calls of one kind, each with its step program's and its attention
-kernels' device time."""
+calls of one kind, each with its step program's and its kernels' device
+time, and the work that the call's tenant's architecture
+(``bench/arch/<arch>.py``) counts for it."""
 from . import workcount
 
 
@@ -16,20 +17,26 @@ def step_mfu(rec, kind):
     dev = sum(d["device_ns"] for _, d in pairs) * 1e-9
     if not dev:
         return None
-    flops = sum(workcount.step_flops(rec.models[c.tenant], c.rows, c.kind,
-                                     c.logit_rows) for c, _ in pairs)
+    flops = sum(rec.archs[c.tenant].step_flops(
+        rec.models[c.tenant], c.rows, c.kind, c.logit_rows)
+        for c, _ in pairs)
     return 100.0 * flops / (dev * rec.peak["flops"])
 
 
-def attn_roofline(rec, kind):
-    pairs = traced(rec, kind)
-    kern = sum(d["kernel_ns"] for _, d in pairs) * 1e-9
+def kernel_roofline(rec, kind, marker):
+    """The least time the chip needs for the work of the kernel named by
+    ``marker`` (its architecture's count, over every layer that runs it)
+    over that kernel's device time, in the traced calls of ``kind`` whose
+    tenant's architecture lists it."""
+    pairs = [(c, d) for c, d in traced(rec, kind)
+             if marker in rec.archs[c.tenant].kernels]
+    kern = sum(d["kernels"].get(marker, 0) for _, d in pairs) * 1e-9
     if not kern:
         return None
     need = 0.0
     for c, _ in pairs:
         m = rec.models[c.tenant]
-        need += m.layers * workcount.roofline_s(
-            workcount.attn_flops(m, c.rows, c.kind),
-            workcount.attn_bytes(m, c.rows, c.kind), rec.peak)
+        flops, nbytes, layers = rec.archs[c.tenant].kernels[marker]
+        need += layers(m) * workcount.roofline_s(
+            flops(m, c.rows, c.kind), nbytes(m, c.rows, c.kind), rec.peak)
     return 100.0 * need / kern

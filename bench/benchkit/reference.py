@@ -1,14 +1,13 @@
-"""The plain reference: the served decoder's forward pass over a whole
-sequence in ``jax.numpy``, float32 and matmul precision ``highest``, with no
-cache, no batching and no kernel, run layer by layer so that it fits beside
+"""The plain reference: a served decoder's forward pass over a whole sequence
+in ``jax.numpy``, float32 and matmul precision ``highest``, with no cache,
+no batching and no kernel, run layer by layer so that it fits beside
 nothing else on the chip. It imports nothing of the program.
 
-The architecture is the one both configurations serve: a pre-norm GQA
-decoder; RMSNorm with scale ``1 + gamma``; optional RMSNorm of each query
-and key head (``qk_norm``) before rotary embedding; rotary embedding over
-the whole head, rotating the two halves (``rotate_half``); causal softmax
-attention scaled by ``head_dim ** -0.5``; a SwiGLU MLP; a final RMSNorm and
-a tied or separate output matrix.
+The layers are the architecture module's (``bench/arch/<arch>.py``:
+``embed``, ``layer`` and ``head``); this module pads the sequence, runs the
+layers one compiled program at a time and reads the logits of the rows
+asked for. The helpers below (``_q8``, ``_linear``, ``_rms``, ``_rope``)
+are shared by the architecture modules.
 
 ``precision="fp8"`` is the control: the same pass with every linear layer
 (the projections, the MLP and the output matrix) fed float8 e4m3 weights
@@ -23,8 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .model import Model
 
 E4M3_MAX = 448.0
 
@@ -64,62 +61,30 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _layer(x, layers, i, m: Model, fp8: bool):
-    p = jax.tree.map(lambda a: a[i], layers)
-    T = x.shape[0]
-    h = _rms(x, p["ln1"], m.eps)
-    a = p["attn"]
-    q = _linear(h, a["wq"], "td,dhk->thk", 0, fp8)
-    k = _linear(h, a["wk"], "td,dhk->thk", 0, fp8)
-    v = _linear(h, a["wv"], "td,dhk->thk", 0, fp8)
-    if m.qk_norm:
-        q = _rms(q, a["q_gamma"], m.eps)
-        k = _rms(k, a["k_gamma"], m.eps)
-    q, k = _rope(q, m.theta), _rope(k, m.theta)
-    g = m.heads // m.kv_heads
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
-    s = jnp.einsum("thk,shk->hts", q, k) * (m.head_dim ** -0.5)
-    causal = jnp.arange(T)[None, :, None] >= jnp.arange(T)[None, None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    o = jnp.einsum("hts,shk->thk", jax.nn.softmax(s, axis=-1), v)
-    x = x + _linear(o, a["wo"], "thk,hkd->td", (0, 1), fp8)
-    h = _rms(x, p["ln2"], m.eps)
-    f = p["mlp"]
-    up = (jax.nn.silu(_linear(h, f["w_gate"], "td,df->tf", 0, fp8))
-          * _linear(h, f["w_up"], "td,df->tf", 0, fp8))
-    return x + _linear(up, f["w_down"], "tf,fd->td", 0, fp8)
-
-
-def _head(x, rows, params, m: Model, fp8: bool):
-    h = _rms(x[rows], params["final_ln"], m.eps)
-    if m.tied:
-        return _linear(h, params["embed"], "td,vd->tv", 1, fp8)
-    return _linear(h, params["unembed"], "td,dv->tv", 0, fp8)
-
-
 @functools.lru_cache(maxsize=None)
-def _fns(m: Model, fp8: bool):
-    layer = jax.jit(functools.partial(_layer, m=m, fp8=fp8))
-    head = jax.jit(functools.partial(_head, m=m, fp8=fp8))
-    embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
+def _fns(arch, m, fp8: bool):
+    layer = jax.jit(functools.partial(arch.layer, m=m, fp8=fp8))
+    head = jax.jit(functools.partial(arch.head, m=m, fp8=fp8))
+    embed = jax.jit(functools.partial(arch.embed, m=m))
     return embed, layer, head
 
 
-def logits(params, m: Model, tokens, rows, *, pad_to: int,
+def logits(params, arch, m, tokens, rows, *, pad_to: int,
            precision: str = "f32"):
     """Reference logits [len(rows), vocab] (float32, numpy) of ``tokens``
-    at sequence positions ``rows``. The sequence is padded at its end to
-    ``pad_to`` tokens, which the causal mask keeps from every real row, so
-    that one compiled program serves every length."""
+    at sequence positions ``rows``, by the architecture module ``arch`` for
+    its model ``m``. The sequence is padded at its end to ``pad_to``
+    tokens, which the causal mask keeps from every real row, so that one
+    compiled program serves every length; the layer index is an argument
+    of the layer's program, so that one program serves every layer."""
     assert precision in ("f32", "fp8"), precision
-    embed, layer, head = _fns(m, precision == "fp8")
+    embed, layer, head = _fns(arch, m, precision == "fp8")
     toks = np.zeros(pad_to, np.int32)
     toks[:len(tokens)] = tokens
     with jax.default_matmul_precision("highest"):
-        x = embed(params["embed"], jnp.asarray(toks))
+        x = embed(params, jnp.asarray(toks))
         for i in range(m.layers):
-            x = layer(x, params["layers"]["s0"], i)
+            x = layer(x, params, i)
         out = head(x, jnp.asarray(np.asarray(rows, np.int32)), params)
     return np.asarray(out)
 

@@ -17,6 +17,10 @@ operations. They nest on the engine's one host thread:
 * ``engine.emit``      token bookkeeping after the sync (``tenant``,
   ``tokens``, ``finished``).
 
+:func:`benchkit.tracing.load` reads them (``Trace.program``), and the
+traced run's reduction keeps those of its slice with the slice's idle
+intervals (:func:`benchkit.cell.reduce_trace`), for the readers below.
+
 Each instant of a traced slice belongs to the innermost engine span open
 then (its self-time), or to no engine span. Intersected exactly with the
 intervals in which the device runs no operation, that splits the device's
@@ -31,27 +35,10 @@ from typing import Dict, List, Optional
 
 from . import tracing as tr
 
-PREFIX = "engine."
+PREFIX = tr.ENGINE
 # the split's labels, in the order it is printed
 SEAMS = ("sched", "dispatch", "pages", "sync", "emit", "control", "step")
 OUTSIDE = "outside"
-
-
-def load_program(path) -> List[tr.Event]:
-    """The engine's spans in an ``.xplane.pb`` file, by start."""
-    import jax
-    pd = jax.profiler.ProfileData.from_file(str(path))
-    out = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith(PREFIX):
-                    out.append(tr.Event(e.name, int(e.start_ns),
-                                        int(e.end_ns), dict(e.stats)))
-    out.sort(key=lambda e: e.start)
-    return out
 
 
 def segments(program: List[tr.Event], lo: int, hi: int) -> list:

@@ -8,16 +8,60 @@ traffic mix or per-layer metric is a new file and never an edit:
   kinds are the modules ``bench/traffic/<kind>.py``;
 * ``bench/metrics/<metric>.py`` is the reader of one per-layer metric: a
   function ``read(rec)`` over the run's :class:`benchkit.cell.RunRecord`
-  that returns the value, or ``None`` where the run has nothing to read.
+  that returns the value, or ``None`` where the run has nothing to read;
+* ``bench/arch/<arch>.py`` is the architecture that a tenant group of a
+  configuration file names under ``"arch"``.
+
+An architecture module provides:
+
+* ``model(group, dtype)``: a frozen, hashable description of the model as
+  served, read from the group's published ``config`` and its ``served_as``.
+  The harness reads its ``name`` (the program's registry name), ``layers``,
+  ``vocab``, ``d`` and ``dtype``;
+* ``mismatches(m, cfg)``: where the program's ``ModelConfig`` departs from
+  ``m`` (empty when the program serves what the file states);
+* ``params(draw, m)``: the program's parameter tree, every leaf made by
+  ``draw(path, shape, scale)`` (:mod:`benchkit.weights`);
+* ``embed(params, tokens, m)``, ``layer(x, params, i, m, fp8)`` and
+  ``head(x, rows, params, m, fp8)``: the plain reference in float32, run by
+  :mod:`benchkit.reference` one layer at a time (``i`` is traced, so that
+  one program serves every layer; ``fp8`` is the control), built from that
+  module's ``_linear``, ``_rms``, ``_rope`` and ``_q8``;
+* ``step_flops(m, rows, kind, logit_rows)``: the model FLOPs of one decode
+  or chunk call (:mod:`benchkit.workcount` says what ``rows`` hold);
+* ``kernels``: ``{marker: (flops, bytes, layers)}``, one entry for each
+  kernel whose wrapper's name in the device trace contains ``marker``:
+  one layer's operations and bytes of a call, functions of ``(m, rows,
+  kind)``, and ``layers(m)``, how many layers of one step run it.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 REPO_DIR = BENCH_DIR.parent
+
+
+def load_file(path) -> object:
+    """The module in the file ``path`` (``<dir>/<name>.py``), registered as
+    ``bench_<dir>_<name>`` while it runs, as an import would be (a
+    dataclass looks its module up by name). An architecture module that
+    builds on another loads it so: ``load_file(Path(__file__).with_name(
+    "<other>.py"))``."""
+    path = Path(path)
+    name = f"bench_{path.parent.name}_{path.stem}".replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return mod
 
 
 class Spec:
@@ -50,17 +94,17 @@ class Spec:
         if label not in self._kinds:
             if not path.is_file():
                 raise KeyError(f"no file {path} for {label!r}")
-            spec = importlib.util.spec_from_file_location(
-                f"bench_{label.replace('.', '_').replace('/', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            self._kinds[label] = mod
+            self._kinds[label] = load_file(path)
         return self._kinds[label]
 
     def kind(self, kind: str):
         """The traffic kind module ``bench/traffic/<kind>.py``."""
         return self._module(self.dir / "traffic" / f"{kind}.py",
                             f"traffic/{kind}")
+
+    def arch(self, name: str):
+        """The architecture module ``bench/arch/<name>.py``."""
+        return self._module(self.dir / "arch" / f"{name}.py", f"arch/{name}")
 
     def metric_reader(self, metric: str):
         """``read(rec)`` of the per-layer metric ``metric``."""

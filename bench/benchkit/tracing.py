@@ -19,9 +19,14 @@ tenants' steps come from the same jitted closures, so a program's name
 does not say which tenant it served: the reduction pairs the step
 programs, in the order the device ran them, with the calls the harness
 recorded in the order it made them, and assigns every operation to the
-step program whose execution encloses it. Both attention kernels are
-Pallas kernels named ``_paged_kernel``; the trace names each custom call
-after the jitted wrapper around it, which the kernel matcher reads.
+step program whose execution encloses it. A Pallas kernel is a custom call
+that the trace names after the jitted wrapper around it; the kernel matcher
+reads the markers that the served architectures list (``kernels`` of
+``bench/arch/<arch>.py``).
+
+Besides the harness's spans, the reduction keeps the engine's own
+``engine.*`` spans (``repro.obs.span``, :mod:`benchkit.spans`) with their
+arguments, so that a reader can take what the engine counted.
 """
 from __future__ import annotations
 
@@ -31,9 +36,7 @@ from dataclasses import dataclass, field
 from typing import List, Optional
 
 STEP_PROGRAMS = ("_decode_paged", "_chunk_paged")
-# a Pallas kernel is a custom call that the TPU trace names after the
-# jitted wrapper around it (``decode_attention_paged.3 = ... custom-call``)
-KERNEL_MARKERS = ("attention_paged",)
+HARNESS, ENGINE = "bench.", "engine."
 
 
 @dataclass
@@ -50,6 +53,7 @@ class Trace:
     modules: List[Event]   # device program executions (one device)
     ops: List[Event]       # device operations (one device)
     n_devices: int = 1
+    program: List[Event] = field(default_factory=list)  # engine.* spans
 
 
 def _stats(e) -> dict:
@@ -57,11 +61,11 @@ def _stats(e) -> dict:
 
 
 def load(path) -> Trace:
-    """Read the harness spans and the first accelerator's programs and
-    operations from an ``.xplane.pb`` file."""
+    """Read the harness's and the engine's spans and the first
+    accelerator's programs and operations from an ``.xplane.pb`` file."""
     import jax
     pd = jax.profiler.ProfileData.from_file(str(path))
-    host, modules, ops = [], [], []
+    host, modules, ops, program = [], [], [], []
     devices = [p for p in pd.planes if p.name.startswith("/device:TPU:")
                and p.name[len("/device:TPU:"):].isdigit()]
     for plane in pd.planes:
@@ -69,8 +73,10 @@ def load(path) -> Trace:
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith("bench."):
-                    host.append(Event(e.name, int(e.start_ns),
+                dest = (host if e.name.startswith(HARNESS) else
+                        program if e.name.startswith(ENGINE) else None)
+                if dest is not None:
+                    dest.append(Event(e.name, int(e.start_ns),
                                       int(e.end_ns), _stats(e)))
     if devices:
         for line in devices[0].lines:
@@ -79,9 +85,10 @@ def load(path) -> Trace:
                 continue
             for e in line.events:
                 dest.append(Event(e.name, int(e.start_ns), int(e.end_ns)))
-    for lst in (host, modules, ops):
+    for lst in (host, modules, ops, program):
         lst.sort(key=lambda e: e.start)
-    return Trace(host, modules, ops, n_devices=len(devices))
+    return Trace(host, modules, ops, n_devices=len(devices),
+                 program=program)
 
 
 def window(trace: Trace) -> Optional[Event]:
@@ -151,25 +158,42 @@ def short_name(name: str) -> str:
     return name.split(" = ")[0].lstrip("%")
 
 
-def is_kernel(op: Event) -> bool:
-    return any(k in short_name(op.name) for k in KERNEL_MARKERS) and (
-        "custom-call" in op.name or "custom_call" in op.name)
+def kernel_of(op: Event, markers) -> Optional[str]:
+    """The first of ``markers`` in the name of ``op``, where ``op`` is a
+    custom call (a Pallas kernel); else ``None``."""
+    if "custom-call" not in op.name and "custom_call" not in op.name:
+        return None
+    name = short_name(op.name)
+    return next((k for k in markers if k in name), None)
 
 
-def per_module(trace: Trace, mods: List[Event]) -> List[dict]:
-    """For each step-program execution: its device time, and the device
-    time of the attention kernels inside it (ns)."""
+def _encloses_next(ops: List[Event], i: int) -> bool:
+    """A control-flow operation (the layer scan's ``while``) that encloses
+    the operations that follow it: their time is its time."""
+    return i + 1 < len(ops) and ops[i + 1].start < ops[i].end
+
+
+def per_module(trace: Trace, mods: List[Event], markers=()) -> List[dict]:
+    """For each step-program execution: its device time, the device time of
+    the kernels named by ``markers`` inside it (in all, and by marker), and
+    its device time by operation short name (``ops``: ``{name: ns}``, an
+    operation that encloses others left out, as in :func:`top_ops`)."""
     starts = [o.start for o in trace.ops]
     out = []
     for m in mods:
         i = bisect.bisect_left(starts, m.start)
-        kern = 0
+        kern, by_op = defaultdict(int), defaultdict(int)
         while i < len(trace.ops) and trace.ops[i].start < m.end:
-            if is_kernel(trace.ops[i]):
-                kern += trace.ops[i].end - trace.ops[i].start
+            o = trace.ops[i]
+            k = kernel_of(o, markers)
+            if k is not None:
+                kern[k] += o.end - o.start
+            if not _encloses_next(trace.ops, i):
+                by_op[short_name(o.name)] += o.end - o.start
             i += 1
-        out.append({"device_ns": m.end - m.start, "kernel_ns": kern,
-                    "name": m.name})
+        out.append({"device_ns": m.end - m.start,
+                    "kernel_ns": sum(kern.values()), "kernels": dict(kern),
+                    "ops": dict(by_op), "name": m.name})
     return out
 
 
@@ -184,10 +208,8 @@ def top_ops(trace: Trace, mods: List[Event], labels: List[str], lo: int,
     tot = defaultdict(int)
     ops = trace.ops
     for i, o in enumerate(ops):
-        if not lo <= o.start < hi:
+        if not lo <= o.start < hi or _encloses_next(ops, i):
             continue
-        if i + 1 < len(ops) and ops[i + 1].start < o.end:
-            continue     # a loop around the operations that follow it
         j = bisect.bisect_right(starts, o.start) - 1
         lab = (bounds[j][2] if j >= 0 and o.start < bounds[j][1]
                else "other programs")

@@ -1,7 +1,10 @@
 """Operations and bytes that the algorithm needs, counted from the work the
 traffic really asked for: live rows, their real context lengths and real
 chunk tokens -- never the padded ``n_slots x Sq`` shape a step computes,
-so the counts stay right when a later change packs rows.
+so the counts stay right when a later change packs rows. The counts of a
+step and of each kernel belong to the architecture (``bench/arch/<arch>.py``:
+``step_flops`` and ``kernels``); this module keeps the chip's peaks, the
+roofline and the helpers the counts share.
 
 ``rows`` of a decode call are the live rows' positions (the new token at
 ``pos`` attends to ``pos + 1`` keys). ``rows`` of a chunk call are (start,
@@ -10,8 +13,6 @@ Bytes are those a kernel must move at least once: its inputs and output,
 each once; weights are counted at the served type's width.
 """
 from __future__ import annotations
-
-from .model import Model
 
 # the TPU v5e peaks, by ``device.device_kind`` as JAX reports it. Source:
 # Google Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s
@@ -31,42 +32,14 @@ def peaks(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def _itemsize(m: Model) -> int:
+def itemsize(m) -> int:
+    """Bytes per element of the model's served type."""
     return {"bfloat16": 2, "float16": 2, "float32": 4}[m.dtype]
 
 
-def _queries(rows, kind):
+def queries(rows, kind):
     """[(start, n_queries)] per live row."""
     return [(p, 1) for p in rows] if kind == "decode" else list(rows)
-
-
-def attn_flops(m: Model, rows, kind: str) -> float:
-    """One layer's attention kernel: QK^T and PV over the causal context,
-    2 * 2 * heads * head_dim per (query, key) pair."""
-    pairs = sum(n * s + n * (n + 1) // 2 for s, n in _queries(rows, kind))
-    return 4.0 * m.heads * m.head_dim * pairs
-
-
-def attn_bytes(m: Model, rows, kind: str) -> float:
-    """One layer's attention kernel: each row's keys and values over its
-    context read once, its queries read and its outputs written once."""
-    b = _itemsize(m)
-    total = 0
-    for s, n in _queries(rows, kind):
-        total += 2 * m.kv_heads * m.head_dim * (s + n)   # K and V
-        total += 2 * n * m.heads * m.head_dim            # Q in, O out
-    return float(total * b)
-
-
-def step_flops(m: Model, rows, kind: str, logit_rows: int) -> float:
-    """A whole decode or chunk step: every layer's matmuls for each real
-    token, every layer's attention, and the output matrix for the
-    ``logit_rows`` rows whose logits are used (a decode row's next token;
-    a chunk row that completes its prompt)."""
-    tokens = sum(n for _, n in _queries(rows, kind))
-    return (2.0 * m.layer_matmul_params * m.layers * tokens
-            + m.layers * attn_flops(m, rows, kind)
-            + 2.0 * m.d * m.vocab * logit_rows)
 
 
 def roofline_s(flops: float, nbytes: float, peak: dict) -> float:

@@ -1,6 +1,6 @@
 """Model step: model FLOPs of the real tokens in the traced decode steps
-(``benchkit.workcount.step_flops``) over those steps' device time times
-the chip's peak."""
+(``step_flops`` of the tenant's architecture) over those steps' device
+time times the chip's peak."""
 from benchkit.readers import step_mfu
 
 

@@ -37,7 +37,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(HERE), "src"))
 def reduce(path) -> dict:
     from benchkit import spans, tracing
     trace = tracing.load(path)
-    program = spans.load_program(path)
+    program = trace.program
     w = tracing.window(trace)
     if w is None:
         return {}

@@ -49,8 +49,8 @@ def main(argv=None):
     sp = spec.Spec()
     conf = sp.config(args.config)
     mix = sp.traffic(args.traffic)
-    models, tenants, limits = cell._tenants(conf)
-    eng = cell.build(conf, args.seed, models)
+    models, archs, tenants, limits = cell._tenants(sp, conf)
+    eng = cell.build(conf, args.seed, models, archs)
     calls = []
     cell.instrument(eng, calls)
     rates = [float(r) for r in args.rates.split(",")]
@@ -66,7 +66,8 @@ def main(argv=None):
         m["ls"]["arrivals"]["rate_per_s"] = rate
         streams = traffic.streams(sp, m, args.seed, args.seconds,
                                   cell.TAIL_S)
-        rec = cell.RunRecord("sweep", args.seconds, models, tenants, limits)
+        rec = cell.RunRecord("sweep", args.seconds, models, tenants, limits,
+                             archs)
         calls.clear()
         cell.serve_window(eng, rec, streams, args.seconds, calls)
         for s in rec.sent:

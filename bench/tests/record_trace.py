@@ -33,9 +33,10 @@ def main():
     args = ap.parse_args()
     from benchkit import cell, spec, tracing
     if args.bench_dir:
-        # a data-only directory: borrow the benchmark's kinds and readers
+        # a data-only directory: borrow the benchmark's kinds, readers and
+        # architectures
         d = tempfile.mkdtemp()
-        for sub in ("traffic", "metrics"):
+        for sub in ("traffic", "metrics", "arch"):
             shutil.copytree(os.path.join(BENCH, sub), os.path.join(d, sub))
         for sub in ("configs", "traffic"):
             shutil.copytree(os.path.join(args.bench_dir, sub),

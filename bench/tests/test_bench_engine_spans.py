@@ -74,8 +74,8 @@ def test_trace_from_before_engine_spans_reads_nothing(tmp_path):
     path = tmp_path / "tiny.xplane.pb"
     with gzip.open(f"{benchtest.DATA}/tiny-colo.xplane.pb.gz") as src:
         path.write_bytes(src.read())
-    assert spans.load_program(path) == []
     t = tr.load(path)
+    assert t.program == []
     w = tr.window(t)
     idle = spans.idle_intervals(t.ops, w.start, w.end)
     assert spans.idle_engine_pct([], idle, w.start, w.end) is None
@@ -85,8 +85,8 @@ def test_trace_from_before_engine_spans_reads_nothing(tmp_path):
 def test_tiny_cell_spans_nest_in_steps_and_count_the_calls(tmp_path):
     sp = benchtest.tiny_spec(tmp_path / "bench")
     conf = sp.config("tiny-colo")
-    models, tenants, _ = cell._tenants(conf)
-    eng = cell.build(conf, 2**31 + 5, models)
+    models, archs, tenants, _ = cell._tenants(sp, conf)
+    eng = cell.build(conf, 2**31 + 5, models, archs)
     calls = []
     cell.instrument(eng, calls)
     rng = np.random.default_rng(3)
@@ -105,7 +105,7 @@ def test_tiny_cell_spans_nest_in_steps_and_count_the_calls(tmp_path):
     finally:
         jax.profiler.stop_trace()
     path = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)[0]
-    program = spans.load_program(path)
+    program = tr.load(path).program
     names = {e.name for e in program}
     assert {"engine.step", "engine.sched", "engine.dispatch",
             "engine.pages", "engine.sync", "engine.emit"} <= names
@@ -135,8 +135,8 @@ def test_tiny_cell_spans_nest_in_steps_and_count_the_calls(tmp_path):
 def test_tenant_programs_keep_the_step_program_names(tmp_path):
     sp = benchtest.tiny_spec(tmp_path / "bench")
     conf = sp.config("tiny-colo")
-    models, _, _ = cell._tenants(conf)
-    eng = cell.build(conf, 2**31 + 5, models)
+    models, archs, _, _ = cell._tenants(sp, conf)
+    eng = cell.build(conf, 2**31 + 5, models, archs)
     modules = set()
     for t, rt in eng.tenants.items():
         pt = rt.kv.device_page_table()

@@ -6,9 +6,10 @@ import pytest
 
 import benchtest
 from benchkit import cell, spec, stats, traffic
-from benchkit.model import Model
+from benchkit import tracing as tr
 
 SPEC = spec.Spec()
+DG = SPEC.arch("dense_gqa")
 LIM = {"ttft_ms": 1000.0, "tbt_ms": 100.0}
 
 
@@ -98,30 +99,42 @@ def test_host_readers():
     # nothing traced: device readers stay silent rather than read 0
     for m in ("device_idle_pct", "step_mfu_pct.decode",
               "step_mfu_pct.prefill", "decode_attn_roofline",
-              "prefill_attn_roofline"):
+              "prefill_attn_roofline", "idle_engine_pct",
+              "step_host_ms_p50"):
         assert read(m)(rec) is None
 
 
 def test_device_readers_on_a_traced_record():
-    m = Model("m", 64, 2, 4, 2, 16, 128, 1000, 1e-6, 1e4, False, True,
-              "bfloat16")
+    m = DG.Model("m", 64, 2, 4, 2, 16, 128, 1000, 1e-6, 1e4, False, True,
+                 "bfloat16")
     dec = cell.Call(0, "ls:x", "decode", 1, 4, [10, 20], 2)
     chk = cell.Call(0, "ls:x", "chunk", 8, 4, [(0, 8)], 0)
-    rec = _rec([], models={"ls:x": m})
+    rec = _rec([], models={"ls:x": m}, archs={"ls:x": DG})
     rec.peak = {"flops": 1e12, "hbm_bytes_per_s": 1e11}
     rec.traced = {"busy_s": 0.75, "window_s": 1.0, "aligned": [
-        (dec, {"device_ns": 1000, "kernel_ns": 100}),
-        (chk, {"device_ns": 2000, "kernel_ns": 400})]}
+        (dec, {"device_ns": 1000, "kernel_ns": 100,
+               "kernels": {"attention_paged": 100}}),
+        (chk, {"device_ns": 2000, "kernel_ns": 400,
+               "kernels": {"attention_paged": 400}})]}
     read = SPEC.metric_reader
     assert read("device_idle_pct")(rec) == pytest.approx(25.0)
     from benchkit import workcount as wc
-    flops = wc.step_flops(m, [10, 20], "decode", 2)
+    flops = DG.step_flops(m, [10, 20], "decode", 2)
     assert read("step_mfu_pct.decode")(rec) == pytest.approx(
         100 * flops / (1e-6 * 1e12))
-    need = 2 * wc.roofline_s(wc.attn_flops(m, [(0, 8)], "chunk"),
-                             wc.attn_bytes(m, [(0, 8)], "chunk"), rec.peak)
+    need = 2 * wc.roofline_s(DG.attn_flops(m, [(0, 8)], "chunk"),
+                             DG.attn_bytes(m, [(0, 8)], "chunk"), rec.peak)
     assert read("prefill_attn_roofline")(rec) == pytest.approx(
         100 * need / 400e-9)
+    # the kept engine spans: one step of 100 ms that waits 40 ms on its
+    # argmax, with the device idle for its first 30 ms
+    E = tr.Event
+    rec.traced.update(slice=(0, 120_000_000), idle=[(0, 30_000_000)],
+                      engine_spans=[E("engine.step", 0, 100_000_000),
+                                    E("engine.sync", 40_000_000,
+                                      80_000_000)])
+    assert read("idle_engine_pct")(rec) == pytest.approx(25.0)
+    assert read("step_host_ms_p50")(rec) == pytest.approx(60.0)
 
 
 def test_tail_and_colocated_readers_match_their_definitions():
@@ -146,7 +159,7 @@ def test_tail_and_colocated_readers_match_their_definitions():
         100 * (64 + 3 * 256) / (6 * 64 + 6 * 256))
     for m in ("step_mfu_pct.decode", "step_mfu_pct.prefill",
               "decode_attn_roofline", "prefill_attn_roofline",
-              "device_idle_pct"):
+              "device_idle_pct", "idle_engine_pct", "step_host_ms_p50"):
         assert read(m)(rec) is None      # nothing traced
 
 

@@ -47,6 +47,132 @@ def test_new_config_mix_and_metric_need_only_new_files(tmp_path):
     assert list(res)[-1] == "checks"
 
 
+TOY_ARCH = '''"""A test's architecture: dense_gqa's attention with a non-gated
+squared-ReLU MLP, as the program serves ``mlp_act="sq_relu"``."""
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+
+from benchkit.reference import _linear, _rms
+from benchkit.spec import load_file
+from benchkit.workcount import queries
+
+base = load_file(Path(__file__).with_name("dense_gqa.py"))
+model, embed, head, kernels = base.model, base.embed, base.head, base.kernels
+
+
+def mismatches(m, cfg):
+    bad = base.mismatches(m, cfg.replace(mlp_act="swiglu"))
+    if cfg.mlp_act != "sq_relu":
+        bad.append(f"mlp_act: program {cfg.mlp_act!r} != file 'sq_relu'")
+    return bad
+
+
+def params(draw, m):
+    p = base.params(draw, m)
+    L, D, F = m.layers, m.d, m.ff
+    p["layers"]["s0"]["mlp"] = {"w_up": draw("w_up", (L, D, F), D ** -0.5),
+                                "w_down": draw("w_down", (L, F, D),
+                                               F ** -0.5)}
+    return p
+
+
+def layer(x, params, i, m, fp8):
+    p = jax.tree.map(lambda a: a[i], params["layers"]["s0"])
+    x = x + base.attention(_rms(x, p["ln1"], m.eps), p["attn"], m, fp8)
+    h = _rms(x, p["ln2"], m.eps)
+    up = jnp.square(jax.nn.relu(
+        _linear(h, p["mlp"]["w_up"], "td,df->tf", 0, fp8)))
+    return x + _linear(up, p["mlp"]["w_down"], "tf,fd->td", 0, fp8)
+
+
+def step_flops(m, rows, kind, logit_rows):
+    tokens = sum(n for _, n in queries(rows, kind))
+    return (base.step_flops(m, rows, kind, logit_rows)
+            - 2.0 * m.d * m.ff * m.layers * tokens)     # no gate matrix
+'''
+
+KERNEL_METRIC = '''"""Device ms per traced decode step of the operations whose names hold
+``attention_paged``, from each step's per-operation map."""
+
+
+def read(rec):
+    per = [d for c, d in (rec.traced or {}).get("aligned") or []
+           if c.kind == "decode"]
+    ns = sum(v for d in per for k, v in d["ops"].items()
+             if "attention_paged" in k)
+    return ns / 1e6 / len(per) if ns else None
+'''
+
+DISPATCH_METRIC = '''"""Mean ``live`` argument of the engine's dispatch spans in the traced
+slice."""
+
+
+def read(rec):
+    t = rec.traced or {}
+    if "slice" not in t:
+        return None
+    lo, hi = t["slice"]
+    live = [int(e.args["live"]) for e in t["engine_spans"]
+            if e.name == "engine.dispatch" and lo <= e.start < hi]
+    return sum(live) / len(live) if live else None
+'''
+
+
+def test_new_architecture_config_and_readers_need_only_new_files(tmp_path):
+    """A second architecture, a configuration that names it and two
+    readers -- one of a kernel by name from the per-operation map, one of
+    an ``engine.dispatch`` argument from the kept spans -- are new files
+    and nothing else. A CPU profile records no device plane, so the kernel
+    reader reads the trace recorded on the chip."""
+    with open(f"{benchtest.DATA}/configs/tiny-solo.json") as f:
+        conf = json.load(f)
+    ls = conf["ls"]
+    ls.update(model="nemotron-4-15b", arch="toy_sq_relu", qk_norm=False)
+    ls["config"].update(num_key_value_heads=1, rope_theta=10000.0,
+                        tie_word_embeddings=False)
+    sp = benchtest.tiny_spec(tmp_path / "bench", {
+        "arch/toy_sq_relu.py": TOY_ARCH,
+        "configs/tiny-sqrelu.json": json.dumps(conf),
+        "metrics/toy_attn_kernel_ms.py": KERNEL_METRIC,
+        "metrics/toy_dispatch_live.py": DISPATCH_METRIC})
+    bm = json.loads((tmp_path / "bench" / "BENCHMARK.json").read_text())
+    bm["workloads"].append({"name": "tiny.sqrelu", "config": "tiny-sqrelu",
+                            "traffic": "tiny-solo", "chips": 1,
+                            "why": "test"})
+    for name, src in (("toy_attn_kernel_ms", "device_trace"),
+                      ("toy_dispatch_live", "program_counter")):
+        bm["per_layer"].append({"name": name, "unit": "x",
+                                "better": "higher", "source": src,
+                                "layer": "test", "moves": "ls_tbt_p99_ms",
+                                "workloads": ["tiny.sqrelu"]})
+    (tmp_path / "bench" / "BENCHMARK.json").write_text(json.dumps(bm))
+    res = benchtest.run_tiny(sp, "tiny.sqrelu", seconds=2.0, trace=True)
+    assert res["correct"], res["checks"]
+    assert res["checks"]["ls_logit_gap"]["tokens"] > 0
+    assert 1 <= res["metrics"]["toy_dispatch_live"]["value"] <= 2
+    assert "toy_attn_kernel_ms" not in res["metrics"]     # no device plane
+    rec = benchtest.recorded_record(tmp_path, sp, sp.config("tiny-colo"))
+    assert sp.metric_reader("toy_attn_kernel_ms")(rec) > 0
+    # the architecture's own counts: one matrix fewer than dense_gqa's
+    arch, base = sp.arch("toy_sq_relu"), sp.arch("dense_gqa")
+    m = arch.model(ls, conf["dtype"])
+    assert base.step_flops(m, [5], "decode", 0) - arch.step_flops(
+        m, [5], "decode", 0) == 2.0 * 128 * 256 * 2
+
+
+def test_configuration_naming_a_missing_architecture_fails_at_once(
+        tmp_path):
+    with open(f"{benchtest.DATA}/configs/tiny-solo.json") as f:
+        conf = json.load(f)
+    conf["ls"]["arch"] = "no_such_arch"
+    sp = benchtest.tiny_spec(tmp_path, {
+        "configs/tiny-solo.json": json.dumps(conf)})
+    with pytest.raises(KeyError, match="arch/no_such_arch.py"):
+        benchtest.run_tiny(sp, "tiny.solo")
+
+
 def test_sound_run_is_correct_and_reports_its_metrics(tmp_path):
     sp = benchtest.tiny_spec(tmp_path)
     res = benchtest.run_tiny(sp, "tiny.colo")

@@ -49,7 +49,7 @@ def test_step_programs_and_their_kernels():
     mods = tr.step_modules(t, 0, 1000)
     assert [m.name for m in mods] == ["jit__decode_paged",
                                       "jit__chunk_paged"]
-    per = tr.per_module(t, mods)
+    per = tr.per_module(t, mods, ("attention_paged",))
     assert [p["device_ns"] for p in per] == [250, 100]
     assert [p["kernel_ns"] for p in per] == [100, 50]
     top = tr.top_ops(t, mods, ["ls:x/decode", "be:y/chunk64"], 0, 1000)
@@ -74,7 +74,7 @@ def test_recorded_chip_trace(tmp_path):
              and lo <= e.start < hi]
     mods = tr.step_modules(t, float("-inf"), float("inf"))
     assert len(mods) >= len(calls) > 0
-    per = tr.per_module(t, mods)
+    per = tr.per_module(t, mods, ("attention_paged",))
     # every step program encloses its attention kernels, once per layer
     assert all(0 < p["kernel_ns"] < p["device_ns"] for p in per)
     kinds = {c.args["kind"] for c in calls}

@@ -1,19 +1,20 @@
-"""Work counts against hand-worked shapes of both served models, and the
-peak table."""
+"""Work counts (``bench/arch/dense_gqa.py``) against hand-worked shapes of
+both served models, and the peak table."""
 import json
 import os
 
 import pytest
 
 import benchtest
-from benchkit import workcount
-from benchkit.model import Model
+from benchkit import spec, workcount
+
+DG = spec.Spec().arch("dense_gqa")
 
 
 def _model(cls, config="colo-qwen3-1.7b-stablelm-1.6b"):
     with open(os.path.join(benchtest.BENCH, "configs", f"{config}.json")) as f:
         conf = json.load(f)
-    return Model.from_conf(conf[cls], conf["dtype"])
+    return DG.model(conf[cls], conf["dtype"])
 
 
 QWEN3, STABLELM = _model("ls"), _model("be")
@@ -32,18 +33,18 @@ def test_models_as_published():
 
 def test_decode_attention_qwen3():
     rows = [99, 511]             # live rows' positions: 100 and 512 keys
-    assert workcount.attn_flops(QWEN3, rows, "decode") == 4 * 16 * 128 * 612
+    assert DG.attn_flops(QWEN3, rows, "decode") == 4 * 16 * 128 * 612
     # K and V of 612 tokens at 8 x 128, plus q and o of 16 x 128, bf16
-    assert workcount.attn_bytes(QWEN3, rows, "decode") == 2 * (
+    assert DG.attn_bytes(QWEN3, rows, "decode") == 2 * (
         2 * 8 * 128 * 612 + 2 * 2 * 16 * 128)
 
 
 def test_chunk_attention_stablelm():
     rows = [(0, 256), (256, 64)]
     pairs = 256 * 257 // 2 + 64 * 256 + 64 * 65 // 2
-    assert workcount.attn_flops(STABLELM, rows, "chunk") == \
+    assert DG.attn_flops(STABLELM, rows, "chunk") == \
         4 * 32 * 64 * pairs == 420_741_120
-    assert workcount.attn_bytes(STABLELM, rows, "chunk") == 2 * (
+    assert DG.attn_bytes(STABLELM, rows, "chunk") == 2 * (
         2 * 32 * 64 * (256 + 320) + 2 * (256 + 64) * 32 * 64) == 7_340_032
 
 
@@ -51,10 +52,10 @@ def test_step_flops_counts_real_tokens_only():
     rows = [99, 511]
     want = (2 * 50_331_648 * 28 * 2 + 28 * 4 * 16 * 128 * 612
             + 2 * 2048 * 151936 * 2)
-    assert workcount.step_flops(QWEN3, rows, "decode", 2) == want \
+    assert DG.step_flops(QWEN3, rows, "decode", 2) == want \
         == 7_022_182_400
     # a chunk whose logits are unused pays no output matrix
-    c = workcount.step_flops(STABLELM, [(0, 256)], "chunk", 0)
+    c = DG.step_flops(STABLELM, [(0, 256)], "chunk", 0)
     assert c == (2 * STABLELM.layer_matmul_params * 24 * 256
                  + 24 * 4 * 32 * 64 * (256 * 257 // 2))
 
