@@ -1,6 +1,6 @@
 """Flash-decode (TPU Pallas): one-new-token GQA attention against a KV cache.
 
-Two entry points share one online-softmax kernel body:
+Two entry points:
 
 ``decode_attention``       dense cache [B,Smax,Hkv,D] (or KV-major
                            [B,Hkv,Smax,D] via ``kv_layout="bhsd"``), with
@@ -17,9 +17,18 @@ Ragged early-exit: the kv grid axis is sequential and its BlockSpec index
 map pins every block past a row's last valid block to that last block
 (Pallas elides the copy when consecutive steps request the same block), and
 ``pl.when`` skips the compute — so per-row cost scales with the row's actual
-sequence length, not ``Smax``. Grid: (batch, kv_heads, num_kv_blocks) with
-(m, l, acc) scratch sized [group, D]; all query heads of one KV head are
-processed together (the MXU-friendly GQA decode layout).
+sequence length, not ``Smax``.
+
+Dense grid: (batch, kv_heads, num_kv_blocks) with (m, l, acc) scratch sized
+[group, D]; all query heads of one KV head are processed together (the
+MXU-friendly GQA decode layout). Paged grid: (batch, pages), one step per
+page of a row covering every KV head — the pool stores one page of all heads
+contiguously, and a step costs a fixed overhead whatever it moves, so the
+step count sets the kernel's time. Only where that block would pass
+``PAGE_BLOCK_BYTES`` are the heads split into equal groups (a grid of
+(batch, head groups, pages); :func:`head_groups`). A row at or past the
+table's end (the engine's write sentinel) fetches one block, computes
+nothing and writes zeros.
 """
 from __future__ import annotations
 
@@ -92,11 +101,66 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def _decode_attention_paged_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_ref,
                                    v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                                   scale, block_k, d_major):
-    # the page table and the layer are consumed by the BlockSpec index maps
+                                   scale, page_size, window, kv_axis, d_major):
+    # the page table and the layer are consumed by the BlockSpec index maps;
+    # blocks: q/o [1, h, G, D], k/v [1, h, page, D] ([1, h, D, page] D-major)
     del pt_ref, layer_ref
-    _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-            scale=scale, block_k=block_k, d_major=d_major)
+    b = pl.program_id(0)
+    j = pl.program_id(kv_axis)
+    pos = pos_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # pages past the row's position, and every page of a row past the
+    # window, are pinned by the index map (no new HBM traffic) and skipped
+    @pl.when(jnp.logical_and(pos < window, j <= pos // page_size))
+    def _compute():
+        q = q_ref[0].astype(jnp.float32) * scale             # [h, G, D]
+        k = k_ref[0].astype(jnp.float32)
+        s = jax.lax.dot_general(                             # [h, G, page]
+            q, k, (((2,), (1 if d_major else 2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        k_pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                         2)
+        s = jnp.where(k_pos <= pos, s, NEG_INF)
+
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        v = v_ref[0].astype(jnp.float32)
+        pv = jax.lax.dot_general(                            # [h, G, D]
+            p, v, (((2,), (2 if d_major else 1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = m_new
+
+    # a row that computed nothing has acc 0 and writes zeros
+    @pl.when(j == pl.num_programs(kv_axis) - 1)
+    def _fin():
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+# One page of a head group's K (or V), in the pool's dtype: K and V,
+# double-buffered, hold four such blocks in VMEM.
+PAGE_BLOCK_BYTES = 1 << 20
+
+
+def head_groups(n_kv_heads, page_size, head_dim, itemsize):
+    """The fewest equal groups of the KV heads whose block of one page
+    (heads x page_size x head_dim x itemsize bytes) fits
+    ``PAGE_BLOCK_BYTES``; one head a group where no larger group fits."""
+    per_head = page_size * head_dim * itemsize
+    return next(n for n in range(1, n_kv_heads + 1)
+                if n_kv_heads % n == 0
+                and (n_kv_heads // n * per_head <= PAGE_BLOCK_BYTES
+                     or n == n_kv_heads))
 
 
 def paged_pools(k_pages, v_pages, layer):
@@ -204,7 +268,10 @@ def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
     4-D pool is the ``L = 1``, ``layer = 0`` case); page_table: [B,P] int32
     (entries >= n_pages are unmapped — they are never touched because the kv
     index map clamps to the row's last valid page); pos: [B] int32. The
-    visible window is P * page_size tokens. Returns [B,H,D].
+    visible window is P * page_size tokens; a row at or past it (the
+    engine's write sentinel) reads one page and returns zeros. One grid step
+    reads one page of every KV head (of a head group, where that block
+    passes ``PAGE_BLOCK_BYTES``). Returns [B,H,D].
     """
     if interpret is None:
         interpret = interpret_default()
@@ -215,37 +282,52 @@ def decode_attention_paged(q, k_pages, v_pages, page_table, pos, *,
     tile = k_pages.shape[3:]              # (page, D), or (D, page) D-major
     page_size = tile[1] if d_major else tile[0]
     P = page_table.shape[1]
+    window = P * page_size
     G = H // Hkv
+    groups = head_groups(Hkv, page_size, D, k_pages.dtype.itemsize)
+    hb = Hkv // groups
+    grid = (B, P) if groups == 1 else (B, groups, P)
     qg = q.reshape(B, Hkv, G, D)
     pos_arr = _pos_vector(pos, B)
     pt = jnp.asarray(page_table, jnp.int32)
 
-    def _kv_index(b, h, j, pt, pos, layer):
-        jj = jnp.minimum(j, pos[b] // page_size)
-        return (layer[0], jnp.minimum(pt[b, jj], n_pages - 1), h, 0, 0)
+    def _ids(ids):
+        # (row, head group, page, *scalar prefetch) from the index map's
+        # arguments: the grid's indices, then pt, pos, layer
+        return (ids[0], ids[1] if groups > 1 else 0) + ids[-4:]
+
+    def _row_index(*ids):
+        b, g = _ids(ids)[:2]
+        return (b, g, 0, 0)
+
+    def _kv_index(*ids):
+        b, g, j, pt, pos, layer = _ids(ids)
+        last = jnp.where(pos[b] < window, pos[b] // page_size, 0)
+        jj = jnp.minimum(j, last)
+        return (layer[0], jnp.minimum(pt[b, jj], n_pages - 1), g, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_decode_attention_paged_kernel, scale=D ** -0.5,
-                          block_k=page_size, d_major=d_major),
+                          page_size=page_size, window=window,
+                          kv_axis=len(grid) - 1, d_major=d_major),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, Hkv, P),
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, j, pt, pos, layer: (b, h, 0, 0)),
-                pl.BlockSpec((None, 1, 1) + tile, _kv_index),
-                pl.BlockSpec((None, 1, 1) + tile, _kv_index),
+                pl.BlockSpec((1, hb, G, D), _row_index),
+                pl.BlockSpec((None, 1, hb) + tile, _kv_index),
+                pl.BlockSpec((None, 1, hb) + tile, _kv_index),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, D), lambda b, h, j, pt, pos, layer: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, hb, G, D), _row_index),
             scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
+                pltpu.VMEM((hb, G, 1), jnp.float32),
+                pltpu.VMEM((hb, G, 1), jnp.float32),
+                pltpu.VMEM((hb, G, D), jnp.float32),
             ]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",)),
         interpret=interpret,
     )(pt, pos_arr, layer_arr, qg, k_pages, v_pages)
     return out.reshape(B, H, D)

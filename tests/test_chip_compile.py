@@ -18,7 +18,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attention import (decode_attention,
-                                            decode_attention_paged)
+                                            decode_attention_paged,
+                                            head_groups)
 from repro.kernels.prefill_attention import (prefill_attention,
                                              prefill_attention_paged)
 
@@ -27,6 +28,8 @@ SMAX = 2048    # per-slot context
 # (H, Hkv, D) of the served tenants
 QWEN3 = (16, 8, 128)
 STABLELM = (32, 32, 64)
+# a KV block of all heads past the paged decode kernel's budget (4 MB a page)
+SPLIT_HEADS = (64, 64, 256)
 
 
 @pytest.fixture(scope="module")
@@ -70,11 +73,17 @@ def test_decode_attention_compiles(one_chip, widths):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("widths,page", [(QWEN3, 16), (QWEN3, 128),
-                                         (STABLELM, 128)],
-                         ids=["qwen3-p16", "qwen3-p128", "stablelm-p128"])
-def test_decode_attention_paged_compiles(one_chip, widths, page):
+@pytest.mark.parametrize("widths,page,groups", [(QWEN3, 16, 1),
+                                                (QWEN3, 128, 1),
+                                                (STABLELM, 128, 1),
+                                                (SPLIT_HEADS, 128, 4)],
+                         ids=["qwen3-p16", "qwen3-p128", "stablelm-p128",
+                              "split-heads-p128"])
+def test_decode_attention_paged_compiles(one_chip, widths, page, groups):
+    """One grid step reads a page of every KV head; a block past the budget
+    (64 heads of D 256) splits the heads into groups, which compiles too."""
     H, Hkv, D = widths
+    assert head_groups(Hkv, page, D, 2) == groups
     n_pages = B * SMAX // page
     txt = _compile_text(
         functools.partial(decode_attention_paged, interpret=False),

@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp oracles
 (interpret mode on CPU), plus hypothesis property tests."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -163,6 +165,106 @@ def test_decode_attention_paged_matches_dense():
                                            pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(wantp),
                                rtol=2e-5, atol=2e-5)
+
+
+# the served tenants' (H, Hkv, D): qwen3-1.7b, stablelm-1.6b
+SERVED_WIDTHS = [(16, 8, 128), (32, 32, 64)]
+
+
+def _paged_case(key, widths, ps, P, B, L=1):
+    """q, stacked pools [L, n_pages, Hkv, ps, D] and a shuffled page table
+    [B, P] with one unmapped entry past row 0's first page."""
+    H, Hkv, D = widths
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.key(key), 3)
+    q = _rand(ks[0], (B, H, D), jnp.float32)
+    kp = _rand(ks[1], (L, n_pages, Hkv, ps, D), jnp.float32)
+    vp = _rand(ks[2], (L, n_pages, Hkv, ps, D), jnp.float32)
+    pt = np.random.default_rng(key).permutation(n_pages)[:B * P]
+    pt = pt.reshape(B, P).astype(np.int32)
+    pt[0, 1:] = n_pages            # row 0 stays on its first page
+    return q, kp, vp, jnp.asarray(pt)
+
+
+@pytest.mark.parametrize("widths", SERVED_WIDTHS, ids=["qwen3", "stablelm"])
+@pytest.mark.parametrize("ps", [16, 128], ids=["p16", "p128"])
+def test_decode_attention_paged_served_widths(widths, ps):
+    """At both served tenants' widths, with pages of 16 and 128 (D 64 at 128
+    takes the D-major view), the kernel matches the paged oracle for ragged
+    positions: the first token, either side of a page boundary, and a row
+    on its table's last page up to the window's last token; every layer of
+    a stacked pool."""
+    P, L = 3, 2
+    pos = jnp.asarray([ps - 1, 0, ps, 2 * ps - 1, 2 * ps + 1, P * ps - 1],
+                      jnp.int32)
+    q, kp, vp, pt = _paged_case(41, widths, ps, P, len(pos), L)
+    for layer in range(L):
+        out = ops.decode_attention_paged(q, kp, vp, pt, pos,
+                                         layer=jnp.asarray(layer))
+        want = ref.ref_decode_attention_paged(q, kp[layer], vp[layer], pt,
+                                              pos)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("widths", SERVED_WIDTHS, ids=["qwen3", "stablelm"])
+@pytest.mark.parametrize("ps", [16, 128], ids=["p16", "p128"])
+def test_decode_attention_paged_row_past_window(widths, ps):
+    """A row at ``pos = P * page_size`` (the engine's write sentinel) reads
+    nothing and returns zeros, and the other rows' outputs are those of the
+    same call without that row."""
+    P = 2
+    q, kp, vp, pt = _paged_case(43, widths, ps, P, 3)
+    pos = jnp.asarray([ps - 3, P * ps, ps + 1], jnp.int32)
+    out = np.asarray(ops.decode_attention_paged(q, kp, vp, pt, pos, layer=0))
+    keep = np.asarray([0, 2])
+    alone = ops.decode_attention_paged(q[keep], kp, vp, pt[keep], pos[keep],
+                                       layer=0)
+    np.testing.assert_array_equal(out[keep], np.asarray(alone))
+    np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
+
+
+@pytest.mark.parametrize("shape,groups", [
+    ((8, 128, 128, 2), 1),         # qwen3-1.7b, bf16
+    ((32, 128, 64, 2), 1),         # stablelm-1.6b, bf16
+    ((64, 128, 256, 2), 4),        # 4 MB of heads: four groups of 1 MB
+    ((12, 128, 512, 4), 3),        # 3 MB in float32: halves do not fit
+    ((2, 1024, 512, 4), 2),        # one head past the budget alone
+], ids=["qwen3", "stablelm", "hkv64-d256", "hkv12-thirds", "head-past-budget"])
+def test_paged_decode_head_groups(shape, groups):
+    """The paged decode kernel reads one page of every KV head a step; only
+    a block past the budget splits the heads, into the fewest equal groups
+    that fit (one head a group where none does)."""
+    from repro.kernels.decode_attention import PAGE_BLOCK_BYTES, head_groups
+    Hkv, ps, D, itemsize = shape
+    assert head_groups(Hkv, ps, D, itemsize) == groups
+    assert Hkv % groups == 0
+    per_group = Hkv // groups * ps * D * itemsize
+    assert per_group <= PAGE_BLOCK_BYTES or groups == Hkv
+
+
+def test_decode_attention_paged_split_heads(monkeypatch):
+    """With the block budget shrunk so the heads split into groups (a grid
+    of rows x head groups x pages), the kernel still matches the oracle,
+    a row past the window still returns zeros, and the result equals the
+    one-group call."""
+    # the package exports the function ``decode_attention`` under the
+    # module's name
+    da = importlib.import_module("repro.kernels.decode_attention")
+    H, Hkv, D, ps, P = 8, 4, 64, 16, 3
+    q, kp, vp, pt = _paged_case(47, (H, Hkv, D), ps, P, 3)
+    pos = jnp.asarray([2, 2 * ps, P * ps], jnp.int32)
+    whole = da.decode_attention_paged(q, kp, vp, pt, pos, layer=0,
+                                      interpret=True)
+    monkeypatch.setattr(da, "PAGE_BLOCK_BYTES", 2 * ps * D * 4)
+    assert da.head_groups(Hkv, ps, D, 4) == 2
+    out = np.asarray(da.decode_attention_paged(q, kp, vp, pt, pos, layer=0,
+                                               interpret=True))
+    np.testing.assert_array_equal(out, np.asarray(whole))
+    want = ref.ref_decode_attention_paged(q, kp[0], vp[0], pt, pos)
+    np.testing.assert_allclose(out[:2], np.asarray(want)[:2], rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_array_equal(out[2], np.zeros_like(out[2]))
 
 
 # ---------------------------------------------------------------------------
